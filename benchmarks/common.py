@@ -37,11 +37,12 @@ def bench_steps(scale: float = 1.0) -> int:
     return max(int(BENCH_STEPS * scale), 5)
 
 
-def make_family(family: str, seq_len: int = 64):
-    """'bert' (MLM, post-LN encoder) or 'opt' (CLM, pre-LN decoder)."""
+def make_family(family: str):
+    """'bert' (MLM, post-LN encoder, trained at 128 tokens) or 'opt' (CLM,
+    pre-LN decoder, trained at 256)."""
     if family == "bert":
-        return bert_tiny(vocab=VOCAB, seq_len=seq_len), "mlm"
-    return opt_tiny(vocab=VOCAB, seq_len=seq_len), "clm"
+        return bert_tiny(vocab=VOCAB), "mlm"
+    return opt_tiny(vocab=VOCAB), "clm"
 
 
 def train_and_measure(
@@ -59,8 +60,7 @@ def train_and_measure(
                      optimizer=AdamWConfig(lr=lr),
                      schedule=linear_warmup_linear_decay(steps // 10, steps))
     data = SyntheticLM(SyntheticLMConfig(
-        vocab_size=cfg.vocab_size, seq_len=cfg.max_seq_len
-        if cfg.max_seq_len <= 256 else 64,
+        vocab_size=cfg.vocab_size, seq_len=cfg.train_seq_len,
         batch_size=batch_size, seed=seed))
     t0 = time.perf_counter()
     out = run_training(task, data, LoopConfig(

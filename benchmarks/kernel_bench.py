@@ -68,12 +68,12 @@ def bench_paged(print_fn=print, out_path: str = _BENCH_JSON) -> None:
     rows = []
     for name, sm, gp in variants:
         cfg = AttentionConfig(n_heads=HQ, n_kv_heads=HKV, d_head=DH,
-                              softmax=sm)
+                              softmax=sm, seq_len=max(WS) * BS)
         for w in WS:
             nb = B * LIVE + 2
             q = jax.random.normal(ks[0], (B, 1, HQ, DH))
-            k_pool = jax.random.normal(ks[1], (nb, BS, HKV, DH))
-            v_pool = jax.random.normal(ks[2], (nb, BS, HKV, DH))
+            k_pool = jax.random.normal(ks[1], (nb, HKV, BS, DH))
+            v_pool = jax.random.normal(ks[2], (nb, HKV, BS, DH))
             table = np.full((B, w), -1, np.int32)
             for i in range(B):
                 table[i, :LIVE] = range(i * LIVE, (i + 1) * LIVE)
@@ -82,7 +82,7 @@ def bench_paged(print_fn=print, out_path: str = _BENCH_JSON) -> None:
             def f(backend, lw):
                 return jax.jit(lambda q, t: paged_attention(
                     q, k_pool, v_pool, t, cfg, q_offset=pos, gate_pi=gp,
-                    backend=backend, live_width=lw, interpret=interpret))
+                    backend=backend, live_width=lw))
 
             t_full = _time(f("gather", None), q, table)
             t_live = _time(f("gather", LIVE), q, table)
@@ -146,4 +146,6 @@ def run(print_fn=print) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

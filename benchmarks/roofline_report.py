@@ -51,4 +51,6 @@ def run(print_fn=print, mesh_tag: str = "16x16") -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

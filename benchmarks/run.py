@@ -25,6 +25,7 @@ from benchmarks import (  # noqa: E402
     table10_bitwidths,
     table11_overhead,
 )
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 SECTIONS = [
     ("table2_main", table2_main.run),
@@ -40,7 +41,9 @@ SECTIONS = [
 
 
 def main() -> None:
+    enable_compile_cache()
     only = set(sys.argv[1:])
+    failed = []
     t_all = time.time()
     for name, fn in SECTIONS:
         if only and name not in only:
@@ -49,10 +52,13 @@ def main() -> None:
         print(f"\n===== {name} =====", flush=True)
         try:
             fn()
-        except Exception as e:  # noqa: BLE001 — report and continue
-            print(f"SECTION FAILED: {name}: {e!r}")
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
+            print(f"SECTION FAILED: {name}: {e!r}", flush=True)
+            failed.append(name)
         print(f"# section {name} took {time.time()-t0:.1f}s", flush=True)
     print(f"\n# total {time.time()-t_all:.1f}s")
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

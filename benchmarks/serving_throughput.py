@@ -93,6 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method
 from repro.configs.paper_models import opt_tiny
 from repro.models import model_init
@@ -567,6 +568,7 @@ def bench_spec_decode() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     print(f"decode throughput, max_new_tokens={MAX_NEW}, prompt={PROMPT_LEN}"
           + (" [--smoke]" if SMOKE else ""))
     print("method,batch,generate_tok_s,batcher_tok_s")

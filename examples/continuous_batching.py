@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method
 from repro.configs.paper_models import opt_tiny
 from repro.models import model_init
@@ -20,6 +21,7 @@ EOS_ID = 5          # synthetic EOS: some requests will emit it mid-stream
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = apply_method(opt_tiny(vocab=256, seq_len=64), "clipped_softmax",
                        alpha=4.0)
     params = model_init(jax.random.PRNGKey(0), cfg)

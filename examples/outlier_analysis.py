@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method
 from repro.configs.paper_models import bert_tiny
 from repro.core import outlier_counts_by_dim, outlier_counts_by_token
@@ -38,6 +39,7 @@ def analyze(params, cfg, batch, label):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=400)
     args = ap.parse_args()

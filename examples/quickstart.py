@@ -6,6 +6,7 @@ modifications, run a forward pass, inspect outlier metrics, quantize.
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method, get_arch, list_archs
 from repro.configs.paper_models import opt_tiny
 from repro.core import OutlierStats, clipped_softmax, infinity_norm, kurtosis
@@ -14,6 +15,7 @@ from repro.quant import QConfig, QuantContext, calibrate
 
 
 def main() -> None:
+    enable_compile_cache()
     print("Assigned architecture pool:", ", ".join(list_archs()))
 
     # 1. the paper's core op: exact zeros with finite logits

@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method
 from repro.configs.paper_models import opt_tiny
 from repro.data import SyntheticLM, SyntheticLMConfig
@@ -20,6 +21,7 @@ from repro.serving import GenerateConfig, generate
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = apply_method(opt_tiny(vocab=512, seq_len=64), "clipped_softmax",
                        alpha=4.0)
     cfg = dataclasses.replace(cfg, max_seq_len=128)

@@ -8,11 +8,13 @@ experimental pipeline as one script.
         --smoke --steps 50           # any pool arch (reduced config)
 """
 import argparse
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import apply_method, get_arch
 from repro.configs.paper_models import opt_tiny
 from repro.data import SyntheticLM, SyntheticLMConfig
@@ -24,6 +26,7 @@ from repro.train.losses import loss_for
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="opt-tiny")
     ap.add_argument("--method", default="clipped_softmax",
@@ -42,6 +45,7 @@ def main() -> None:
     else:
         spec = get_arch(args.arch)
         cfg = spec.smoke() if args.smoke else spec.full()
+        cfg = dataclasses.replace(cfg, train_seq_len=args.seq_len)
     cfg = apply_method(cfg, args.method, alpha=4.0, pi_init=0.5)
     loss_kind = "clm" if cfg.causal else "frames"
 

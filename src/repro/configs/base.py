@@ -108,6 +108,10 @@ def apply_method(cfg: ModelConfig, method: str,
         return dataclasses.replace(
             cfg, softmax_cfg=ClippedSoftmaxConfig(), gate_cfg=GateConfig(kind="none"))
     if method == "clipped_softmax":
+        if alpha is not None and cfg.train_seq_len is None:
+            raise ValueError(
+                f"{cfg.name}: gamma = -alpha / T needs the length the model "
+                f"is trained at (ModelConfig.train_seq_len)")
         sm = ClippedSoftmaxConfig(gamma=gamma, zeta=zeta, alpha=alpha)
         return dataclasses.replace(cfg, softmax_cfg=sm, gate_cfg=GateConfig(kind="none"))
     if method == "gated_attention":

@@ -3,6 +3,10 @@
 These drive the paper-table benchmarks; the reduced ``*_tiny`` variants run
 the same protocol at CPU scale (same family: post-LN MLM encoder for BERT,
 pre-LN CLM decoder for OPT, encoder-with-patch-embeds for ViT).
+
+Each constructor sets ``train_seq_len``, the length the model is trained
+at (its ``seq_len`` argument where it has one): the clipped softmax's
+gamma = -alpha / T resolves from it on every path.
 """
 import jax.numpy as jnp
 
@@ -14,7 +18,7 @@ def bert_base() -> ModelConfig:
         name="bert-base", n_layers=12, d_model=768, n_heads=12, n_kv_heads=12,
         d_ff=3072, vocab_size=30522, d_head=64,
         causal=False, norm="layernorm", norm_position="post",
-        mlp_kind="gelu", pos="learned", max_seq_len=512,
+        mlp_kind="gelu", pos="learned", max_seq_len=512, train_seq_len=128,
         tie_embeddings=True, scan_layers=False, remat=False,
     )
 
@@ -25,6 +29,7 @@ def bert_6l(seq_len: int = 128) -> ModelConfig:
         d_ff=3072, vocab_size=30522, d_head=64,
         causal=False, norm="layernorm", norm_position="post",
         mlp_kind="gelu", pos="learned", max_seq_len=max(seq_len, 512),
+        train_seq_len=seq_len,
         tie_embeddings=True, scan_layers=False, remat=False,
     )
 
@@ -36,6 +41,7 @@ def bert_tiny(vocab: int = 2048, seq_len: int = 128) -> ModelConfig:
         d_ff=512, vocab_size=vocab, d_head=32,
         causal=False, norm="layernorm", norm_position="post",
         mlp_kind="gelu", pos="learned", max_seq_len=max(seq_len, 128),
+        train_seq_len=seq_len,
         tie_embeddings=True, scan_layers=False, remat=False,
     )
 
@@ -45,7 +51,7 @@ def opt_125m() -> ModelConfig:
         name="opt-125m", n_layers=12, d_model=768, n_heads=12, n_kv_heads=12,
         d_ff=3072, vocab_size=50272, d_head=64,
         causal=True, norm="layernorm", norm_position="pre",
-        mlp_kind="relu", pos="learned", max_seq_len=2048,
+        mlp_kind="relu", pos="learned", max_seq_len=2048, train_seq_len=512,
         tie_embeddings=True, scan_layers=False, remat=False,
         init_std=0.006,
     )
@@ -57,6 +63,7 @@ def opt_tiny(vocab: int = 2048, seq_len: int = 256) -> ModelConfig:
         d_ff=512, vocab_size=vocab, d_head=32,
         causal=True, norm="layernorm", norm_position="pre",
         mlp_kind="relu", pos="learned", max_seq_len=max(seq_len, 256),
+        train_seq_len=seq_len,
         tie_embeddings=True, scan_layers=False, remat=False,
         init_std=0.006,
     )
@@ -69,7 +76,7 @@ def vit_s16() -> ModelConfig:
         name="vit-s16", n_layers=12, d_model=384, n_heads=6, n_kv_heads=6,
         d_ff=1536, vocab_size=1000, d_head=64,
         causal=False, norm="layernorm", norm_position="pre",
-        mlp_kind="gelu", pos="learned", max_seq_len=256,
+        mlp_kind="gelu", pos="learned", max_seq_len=256, train_seq_len=197,
         input_kind="embeds", frontend_dim=384,
         tie_embeddings=False, scan_layers=False, remat=False,
     )

@@ -2,7 +2,6 @@
 from repro.core.softmax import (
     ClippedSoftmaxConfig,
     clipped_softmax,
-    clipped_softmax_from_config,
     softcap,
     softmax,
     stretch_and_clip,
@@ -27,7 +26,7 @@ from repro.core.outliers import (
 )
 
 __all__ = [
-    "ClippedSoftmaxConfig", "clipped_softmax", "clipped_softmax_from_config",
+    "ClippedSoftmaxConfig", "clipped_softmax",
     "softcap", "softmax", "stretch_and_clip",
     "GateConfig", "gate_logits", "gate_param_count", "gate_probs", "init_gate",
     "AttentionConfig", "attention", "chunked_attention", "dense_attention",

@@ -14,9 +14,9 @@ Three execution paths:
     the 1-pass online path. This is the XLA (non-Pallas) implementation the
     dry-run lowers; `repro.kernels.flash_attention` is the TPU Pallas twin.
   * ``paged_attention``   — serving decode over a paged KV cache: K/V live
-    in a global block pool ``(num_blocks, block_size, Hkv, Dh)`` and each
-    batch row owns a *block table* of physical block ids. A dispatcher over
-    two backends: the fused Pallas TPU kernel
+    in a global head-major block pool ``(num_blocks, Hkv, block_size, Dh)``
+    and each batch row owns a *block table* of physical block ids. A
+    dispatcher over two backends: the fused Pallas TPU kernel
     (``repro.kernels.paged_attention``) that reads pool blocks in place
     through a scalar-prefetched block table (default on TPU), and
     ``paged_attention_gather`` — the XLA oracle that gathers the row's
@@ -27,6 +27,13 @@ Three execution paths:
 
 Layout convention: q (B, Tq, Hq, Dh); k/v (B, Tk, Hkv, Dh) with
 Hq = G * Hkv (grouped-query attention).
+
+The clipped softmax's gamma is ``AttentionConfig.gamma``: a static value,
+or -alpha / ``seq_len`` where ``seq_len`` is the length the model is
+trained at (``ModelConfig.attn_cfg`` passes ``train_seq_len``). No path
+resolves it from the KV axis it happens to read, so a training step, a
+cache-free forward, a dense cache and a paged pool of any width or live
+prefix clip alike.
 
 The ``q_offset`` vector contract (introduced with the per-slot-position
 decode engine, PR 1): everywhere a query block is positioned inside the full
@@ -77,11 +84,17 @@ class AttentionConfig:
     logit_softcap: Optional[float] = None   # gemma-2 style tanh cap
     softmax: ClippedSoftmaxConfig = ClippedSoftmaxConfig()
     chunk_size: int = 512                   # KV block for the chunked path
+    seq_len: Optional[int] = None           # T of gamma = -alpha / T
 
     @property
     def group_size(self) -> int:
         assert self.n_heads % self.n_kv_heads == 0
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def gamma(self) -> float:
+        """The clipped softmax's lower stretch (see the module docstring)."""
+        return self.softmax.resolve_gamma(self.seq_len)
 
 
 def make_attention_mask(
@@ -155,9 +168,8 @@ def dense_attention(
     if sm.is_vanilla:
         probs = softmax(logits, axis=-1, where=mask_b)
     else:
-        gamma = sm.resolve_gamma(tk)
         probs = softmax(logits, axis=-1, where=mask_b)
-        probs = stretch_and_clip(probs, gamma, sm.zeta)
+        probs = stretch_and_clip(probs, cfg.gamma, sm.zeta)
         # clipped probabilities of masked entries are clip(gamma,0,1)=0 since
         # softmax emitted 0 there and gamma <= 0; nothing extra needed.
     probs = probs.astype(v.dtype)
@@ -240,7 +252,7 @@ def _clipped_second_pass(q, k, v, m, z, cfg: AttentionConfig, q_offset) -> Array
     hkv = cfg.n_kv_heads
     c = cfg.chunk_size
     tk = k.shape[1]
-    gamma = cfg.softmax.resolve_gamma(tk)
+    gamma = cfg.gamma
     zeta = cfg.softmax.zeta
     n_chunks = (tk + c - 1) // c
     pad = n_chunks * c - tk
@@ -309,10 +321,7 @@ def paged_attention_gather(
     per-block validity mask additionally hides unallocated entries (id < 0).
     Masked positions contribute exact zeros to the softmax, so the result is
     bitwise identical to dense attention over a contiguous cache of the same
-    length W*block_size holding the same tokens. If ``cfg.softmax`` uses
-    ``alpha``, gamma resolves from the gathered axis length W*block_size —
-    callers slicing the table to a live prefix must pre-resolve gamma from
-    the LOGICAL length (``paged_attention`` does).
+    length W*block_size holding the same tokens.
 
     ``live_widths`` ((B,) int32, optional): each row's OWN count of live
     block-table entries. Entries at or beyond a row's count are treated as
@@ -324,22 +333,25 @@ def paged_attention_gather(
     sliced table, its gather) track the row rather than the widest row in
     the tick.
 
-    ``k_scale``/``v_scale`` ((num_blocks, block_size) f32, optional): the
-    int8 pool's per-slot scale vectors. Dequantization is fused into the
+    ``k_scale``/``v_scale`` ((num_blocks, 1, block_size) f32, optional):
+    the int8 pool's per-slot scale rows. Dequantization is fused into the
     same block gather — scales are gathered with the identical ``safe``
     indices and multiplied back before the softmax, so the virtual KV
     sequence the mask sees is already fp. Stale scales in recycled blocks
     are hidden by the same validity/causal masks as stale KV."""
     b, w = block_table.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    nb, hkv, bs, dh = k_pool.shape
     tq, tk = q.shape[1], w * bs
     valid_entry = block_table >= 0                               # (B, W)
     if live_widths is not None:
         valid_entry &= jnp.arange(w)[None, :] < \
             jnp.asarray(live_widths, jnp.int32)[:, None]
     safe = jnp.where(valid_entry, jnp.clip(block_table, 0, nb - 1), 0)
-    k = k_pool[safe].reshape(b, tk, *k_pool.shape[2:])
-    v = v_pool[safe].reshape(b, tk, *v_pool.shape[2:])
+
+    def gather(pool):            # (B, W, Hkv, BS, Dh) -> (B, W*BS, Hkv, Dh)
+        return pool[safe].transpose(0, 1, 3, 2, 4).reshape(b, tk, hkv, dh)
+
+    k, v = gather(k_pool), gather(v_pool)
     if k_scale is not None:
         ks = k_scale[safe].reshape(b, tk)
         k = k.astype(jnp.float32) * ks[:, :, None, None]
@@ -376,9 +388,10 @@ def paged_attention(
 ) -> Array:
     """Paged-KV attention dispatcher. Returns (B, Tq, Hq, Dh).
 
-    ``k_pool``/``v_pool``: (num_blocks, block_size, Hkv, Dh) global pools
-    shared by every batch row. ``block_table``: (B, W) int32 physical block
-    ids; entry j maps the row's logical token range
+    ``k_pool``/``v_pool``: (num_blocks, Hkv, block_size, Dh) global pools
+    shared by every batch row, head-major so the kernel reads one
+    (block_size, Dh) tile per step. ``block_table``: (B, W) int32 physical
+    block ids; entry j maps the row's logical token range
     [j*block_size, (j+1)*block_size) onto pool block ``block_table[b, j]``,
     with -1 marking an unallocated entry.
 
@@ -387,23 +400,23 @@ def paged_attention(
       * ``"kernel"`` — the fused Pallas TPU kernel
         (``repro.kernels.paged_attention``): pool blocks are read in place
         through a scalar-prefetched block table; no gather, no materialized
-        virtual sequence. Default on TPU.
+        virtual sequence.
       * ``"gather"`` — ``paged_attention_gather``, the XLA path that
         materializes each row's virtual KV sequence. Bitwise-equal to dense
-        attention; the oracle the kernel is swept against, the fallback off
-        TPU (where the kernel would run in slow interpret mode), and the
-        path ``backend="auto"`` picks on CPU/GPU.
+        attention; the oracle the kernel is swept against.
+
+    ``backend="auto"`` follows ``kernels.ops.default_interpret()``: the
+    kernel where it runs compiled (TPU), the gather path where it would
+    only be interpreted. ``interpret`` (tests only) overrides the kernel's
+    mode.
 
     ``live_width``: optional static number of block-table entries actually
     in use (allocation is prefix-dense — the scheduler fills tables from
     entry 0). When given, only ``table[:, :live_width]`` is visited by
     EITHER backend, making the per-tick cost proportional to live tokens
-    instead of the table width W. The clipped softmax's ``alpha`` is
-    resolved against the LOGICAL length W*block_size *before* slicing, so
-    the clip threshold gamma = -alpha/max_len is invariant to how many
-    blocks are live (and to ``live_width`` itself) — positions beyond the
-    live prefix are causally unreachable, so slicing is exact, not an
-    approximation.
+    instead of the table width W. Positions beyond the live prefix are
+    causally unreachable and gamma does not depend on the KV axis, so
+    slicing is exact, not an approximation.
 
     ``live_widths``: optional (B,) int32 vector of each row's OWN live
     entry count, masking the gather path's per-row read at the row rather
@@ -413,32 +426,24 @@ def paged_attention(
     already skip unallocated entries, and a per-row ``pl.when`` early exit
     is on-TPU tuning work (ROADMAP).
 
-    ``k_scale``/``v_scale``: per-slot scale vectors (num_blocks,
+    ``k_scale``/``v_scale``: per-slot scale rows (num_blocks, 1,
     block_size) of an int8 pool (``init_paged_cache(kv_int8=True)``).
     Both backends fuse dequantization into their block reads: the gather
     path gathers scales alongside blocks, the kernel DMAs each block's
-    scale vector through the same table-driven index_map and multiplies in
-    the epilogue of the block load. Scale arrays are pool-indexed, not
-    table-indexed, so ``live_width`` slicing leaves them untouched.
+    scale row through the same table-driven index_map. Scale arrays are
+    pool-indexed, not table-indexed, so ``live_width`` slicing leaves them
+    untouched.
     """
-    b, w_full = block_table.shape
-    bs = k_pool.shape[1]
-    logical_len = w_full * bs
-    sm = cfg.softmax
-    if not sm.is_vanilla:
-        # pin gamma to the logical max_len: dense_attention and the kernel
-        # would otherwise resolve it from the (possibly sliced) KV axis
-        gamma, zeta = sm.resolve_gamma(logical_len), sm.zeta
-        cfg = dataclasses.replace(
-            cfg, softmax=ClippedSoftmaxConfig(gamma=gamma, zeta=zeta))
-    else:
-        gamma, zeta = 0.0, 1.0
+    w_full = block_table.shape[1]
     if live_width is not None:
         block_table = block_table[:, :max(1, min(int(live_width), w_full))]
     if backend == "auto":
-        backend = "kernel" if jax.default_backend() == "tpu" else "gather"
+        from repro.kernels.ops import default_interpret
+        backend = "gather" if default_interpret() else "kernel"
     if backend == "kernel":
         from repro.kernels.paged_attention import paged_mha
+        sm = cfg.softmax
+        gamma, zeta = (0.0, 1.0) if sm.is_vanilla else (cfg.gamma, sm.zeta)
         return paged_mha(q, k_pool, v_pool, block_table, q_offset, gate_pi,
                          causal=cfg.causal, window=cfg.window,
                          softcap=cfg.logit_softcap, gamma=gamma, zeta=zeta,

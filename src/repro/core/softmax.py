@@ -11,7 +11,11 @@ matters empirically (paper Table 1 / Table 8); zeta defaults to 1.
 
 `ClippedSoftmaxConfig.resolve_gamma` implements the sequence-length-robust
 parameterization gamma = -alpha / T from paper Section 5.2 (alpha in [2, 4]
-works across T).
+works across T). T is the length the model is trained at, fixed by its
+config (``ModelConfig.train_seq_len``, see ``AttentionConfig.gamma``),
+never the size of the KV axis at hand: a training step, a cache-free
+forward, a dense cache and a paged pool of any width all clip at the same
+gamma.
 """
 from __future__ import annotations
 
@@ -31,12 +35,16 @@ class ClippedSoftmaxConfig:
 
     gamma: float = 0.0          # lower stretch, <= 0; 0 disables low clipping
     zeta: float = 1.0           # upper stretch, >= 1; 1 disables high clipping
-    # If set, gamma is derived per-call as -alpha / T (paper Sec. 5.2) and the
-    # static `gamma` above is ignored.
+    # If set, gamma = -alpha / T (paper Sec. 5.2) for the length T the
+    # model is trained at, and the static `gamma` above is ignored.
     alpha: Optional[float] = None
 
-    def resolve_gamma(self, seq_len: int) -> float:
+    def resolve_gamma(self, seq_len: Optional[int]) -> float:
         if self.alpha is not None:
+            if seq_len is None:
+                raise ValueError(
+                    "alpha-parameterized clipping needs the length the "
+                    "model is trained at (AttentionConfig.seq_len)")
             return -float(self.alpha) / float(seq_len)
         return float(self.gamma)
 
@@ -83,20 +91,6 @@ def clipped_softmax(
     entries receive zero gradient so outliers stop being rewarded.
     """
     return stretch_and_clip(softmax(logits, axis=axis, where=where), gamma, zeta)
-
-
-def clipped_softmax_from_config(
-    logits: Array,
-    cfg: ClippedSoftmaxConfig,
-    axis: int = -1,
-    where: Optional[Array] = None,
-    seq_len: Optional[int] = None,
-) -> Array:
-    if cfg.is_vanilla:
-        return softmax(logits, axis=axis, where=where)
-    t = seq_len if seq_len is not None else logits.shape[axis]
-    gamma = cfg.resolve_gamma(t)
-    return clipped_softmax(logits, gamma=gamma, zeta=cfg.zeta, axis=axis, where=where)
 
 
 def softcap(logits: Array, cap: Optional[float]) -> Array:

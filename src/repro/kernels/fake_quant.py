@@ -7,10 +7,13 @@ roofline and the win is not re-materializing intermediates)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.ops import default_interpret
 
 
 def _kernel(x_ref, o_ref, *, s, z, n_levels):
@@ -20,9 +23,12 @@ def _kernel(x_ref, o_ref, *, s, z, n_levels):
 
 
 def fake_quant_pallas(x: jax.Array, s: float, z: float, bits: int = 8,
-                      block: int = 1024, interpret: bool = True) -> jax.Array:
+                      block: int = 1024,
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Per-tensor fake-quant; static python-float (s, z) baked into the
     kernel (the PTQ context provides them after calibration)."""
+    if interpret is None:
+        interpret = default_interpret()
     orig_shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -168,10 +170,12 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused multi-head attention; (gamma, zeta) = (0, 1) selects the
     single-pass vanilla path, anything else the two-pass clipped path."""
+    if interpret is None:
+        interpret = default_interpret()
     bh, tq, dh = q.shape
     tk = k.shape[1]
     block_q = min(block_q, tq)

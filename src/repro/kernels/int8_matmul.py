@@ -10,43 +10,53 @@ scheme end-to-end:
     paper's clipped-softmax/gated-attention models have no outliers, so a
     static per-tensor range works (Table 2);
   * weights: per-tensor symmetric int8 (pre-quantized, scale s_w);
-  * integer matmul with the zero-point folded out:
-        (x_q - z_x) @ w_q = x_q @ w_q - z_x * colsum(w_q)
-    accumulated in an int32... kept in f32 scratch here because interpret
-    mode runs on CPU; the dot itself requests int32
-    (``preferred_element_type``) exactly as the MXU path would;
+  * integer matmul with the zero-point folded out exactly: the uint8
+    codes are shifted into int8, x_c = x_q - 128, and
+        (x_q - z_x) @ w_q = x_c @ w_q + (128 - z_x) * colsum(w_q).
+    Both operands enter ``dot_general`` as int8 with
+    ``preferred_element_type=int32`` (the MXU's int8 path; widening them
+    to int32 first is refused by the TPU compiler) and accumulate in an
+    int32 VMEM scratch; the column sums of the weight tiles already in
+    VMEM accumulate next to it, so the correction reads no extra HBM.
+    The integer offset 128 - z_x is scalar-prefetched into SMEM;
   * epilogue: dequantize by s_x * s_w.
 
-Grid (M/bm, N/bn, K/bk), K sequential with an accumulator scratch.
-256x256x256 int8 blocks = 3 x 64 KB operands + 256 KB f32 accumulator,
-comfortably double-buffered in VMEM.
+Grid (M/bm, N/bn, K/bk), K sequential with the accumulator scratch.
+256x256x256 int8 blocks = 2 x 64 KB operands + 256 KB int32 accumulator
++ 256 KB f32 output block, comfortably double-buffered in VMEM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import default_interpret
 
-def _kernel(xq_ref, wq_ref, o_ref, acc_scr, *, n_k, scale, out_dtype):
+
+def _kernel(off_ref, xq_ref, wq_ref, o_ref, acc_scr, cs_scr, *, n_k):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _():
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        cs_scr[...] = jnp.zeros_like(cs_scr)
 
-    # int8 x int8 -> int32 (MXU-native); interpret mode emulates on CPU
+    # int8 x int8 -> int32 on the MXU
+    w = wq_ref[...]
     acc_scr[...] += jax.lax.dot_general(
-        xq_ref[...].astype(jnp.int32), wq_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        xq_ref[...], w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    cs_scr[...] += jnp.sum(w.astype(jnp.int32), axis=0, keepdims=True)
 
     @pl.when(k_idx == n_k - 1)
     def _():
-        o_ref[...] = (acc_scr[...].astype(jnp.float32) * scale).astype(out_dtype)
+        o_ref[...] = (acc_scr[...] + off_ref[0] * cs_scr[...]
+                      ).astype(o_ref.dtype)
 
 
 def quantize_weights_int8(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -68,7 +78,7 @@ def int8_matmul(
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Full W8A8 matmul: per-tensor asymmetric activation quant + integer
     kernel + dequant. Returns f32 (M, N).
@@ -76,7 +86,10 @@ def int8_matmul(
     When ``x_scale``/``x_zero`` are given (PTQ-calibrated static ranges,
     e.g. from QuantContext.act_qparams) the dynamic min/max pass over x is
     skipped — the production serving configuration. Without them the range
-    is derived from this batch (dynamic quantization)."""
+    is derived from this batch (dynamic quantization). ``interpret``
+    defaults to ``kernels.ops.default_interpret()``."""
+    if interpret is None:
+        interpret = default_interpret()
     m, kdim = x.shape
     n = w_q.shape[1]
     # activation quantization (asymmetric uint8, zero-point folded out)
@@ -92,13 +105,14 @@ def int8_matmul(
     else:
         s_x = jnp.float32(x_scale)
         z_x = jnp.float32(0.0 if x_zero is None else x_zero)
-    # (q - z) has range [-255, 255]; real int8 pipelines keep the centered
-    # value saturated to [-127, 127] (the paper's outlier-free activations
-    # make saturation loss negligible — that is the point of the method).
-    xq_c = jnp.clip(jnp.clip(jnp.round(x32 / s_x) + z_x, 0, 255) - z_x,
-                    -127, 127).astype(jnp.int8)
+    # uint8 codes shifted into int8 (exact); the kernel adds back
+    # (128 - z) * colsum(w_q), so no code is clipped beyond [0, 255]
+    xq_c = (jnp.clip(jnp.round(x32 / s_x) + z_x, 0, 255) - 128
+            ).astype(jnp.int8)
+    off = jnp.round(128.0 - z_x).astype(jnp.int32).reshape(1)
 
-    # zero-pad to block multiples: int blocks pad with garbage otherwise
+    # zero-pad to block multiples: int blocks pad with garbage otherwise;
+    # zero weight rows/columns add nothing to the dot or the column sums
     pad_m = (-m) % block_m
     pad_k = (-kdim) % block_k
     pad_n = (-n) % block_n
@@ -109,13 +123,19 @@ def int8_matmul(
 
     grid = (pl.cdiv(m, block_m), pl.cdiv(n, block_n), pl.cdiv(kdim, block_k))
     out = pl.pallas_call(
-        functools.partial(_kernel, n_k=grid[2], scale=1.0, out_dtype=jnp.float32),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-                  pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j))],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
+        functools.partial(_kernel, n_k=grid[2]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_m, block_k), lambda i, j, kk, o: (i, kk)),
+                pl.BlockSpec((block_k, block_n), lambda i, j, kk, o: (kk, j))],
+            out_specs=pl.BlockSpec((block_m, block_n),
+                                   lambda i, j, kk, o: (i, j)),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32),
+                            pltpu.VMEM((1, block_n), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((m + pad_m, n + pad_n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
-    )(xq_c, w_q)
+        name="int8_matmul",
+    )(off, xq_c, w_q)
     return out[:m, :n] * (s_x * w_scale)

@@ -52,7 +52,6 @@ def int8_matmul_ref(x, w_q, w_scale, *, x_bits=8):
     s = jnp.maximum((x_max - x_min) / (n - 1), 1e-8)
     z = jnp.clip(jnp.round(-x_min / s), 0, n - 1)
     xq = jnp.clip(jnp.round(x32 / s) + z, 0, n - 1) - z   # integer grid, f32
-    xq = jnp.clip(xq, -127, 127)                          # int8 saturation
     return (xq * s) @ (w_q.astype(jnp.float32) * w_scale)
 
 

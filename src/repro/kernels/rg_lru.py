@@ -16,11 +16,14 @@ sequences chunk at the ops.py level, carrying h across calls.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ops import default_interpret
 
 
 def _kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref, *, t_len):
@@ -36,9 +39,11 @@ def _kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref, *, t_len):
 
 
 def rglru_pallas(a: jax.Array, b: jax.Array, h0=None, *,
-                 block_d: int = 512, interpret: bool = True):
+                 block_d: int = 512, interpret: Optional[bool] = None):
     """a, b: (B, T, D) f32; h0: (B, D) or None.
     Returns (h (B,T,D) f32, h_last (B,D))."""
+    if interpret is None:
+        interpret = default_interpret()
     bsz, t, d = a.shape
     if h0 is None:
         h0 = jnp.zeros((bsz, d), jnp.float32)

@@ -39,8 +39,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, apply_method, cache_specs, get_arch, input_specs, list_archs
 from repro.distributed.sharding import batch_specs, cache_specs_tree, tree_param_specs
-from repro.launch.mesh import make_production_mesh, compat_set_mesh
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, analyze, model_flops_infer, model_flops_train, normalize_cost_analysis, parse_collectives
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, analyze, model_flops_infer, model_flops_train, parse_collectives
 from repro.models.transformer import ModelConfig, model_init
 from repro.nn.module import flatten_params
 from repro.optim.adamw import AdamWConfig
@@ -98,7 +98,7 @@ def build_lowered(cfg: ModelConfig, shape, mesh, profile: str,
                          in_shardings=(_ns(mesh, state_specs), _ns(mesh, bspecs)),
                          out_shardings=(_ns(mesh, state_specs), None),
                          donate_argnums=(0,))
-        with compat_set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             return jitted.lower(state_shapes, batch)
     params_shapes = jax.eval_shape(lambda: model_init(jax.random.PRNGKey(0), cfg))
     pspecs = tree_param_specs(params_shapes, profile, mesh)
@@ -110,7 +110,7 @@ def build_lowered(cfg: ModelConfig, shape, mesh, profile: str,
                              seq_axis="model")
         jitted = jax.jit(make_prefill_step(cfg),
                          in_shardings=(_ns(mesh, pspecs), _ns(mesh, bspecs)))
-        with compat_set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             return jitted.lower(params_shapes, batch)
     # decode
     cache_shapes = cache_specs(cfg, shape)
@@ -130,12 +130,12 @@ def build_lowered(cfg: ModelConfig, shape, mesh, profile: str,
         out_shardings=(None, _ns(mesh, cspecs)),
         donate_argnums=(1,),
     )
-    with compat_set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         return jitted.lower(params_shapes, cache_shapes, tok, pos)
 
 
 def _cost_triple(compiled) -> Tuple[float, float, float]:
-    ca = normalize_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)),

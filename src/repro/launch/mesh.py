@@ -9,32 +9,11 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # newer jax exposes explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def compat_make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` across jax versions: passes ``axis_types`` (all
-    Auto) when the installed jax has ``jax.sharding.AxisType``, and falls
-    back to the plain call otherwise."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def compat_set_mesh(mesh: Mesh):
-    """``jax.sharding.set_mesh(mesh)`` where available; on older jax, enter
-    the mesh itself (legacy resource-env context). Either way usable as
-    ``with compat_set_mesh(mesh): ...`` around tracing/lowering."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -45,10 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices exist locally (tests / CPU smoke): (1, n)."""
     n = len(jax.devices())
-    return compat_make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))

@@ -132,19 +132,10 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def normalize_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions (older jax returns
-    one dict per device in a list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze(compiled, n_chips: int,
             model_flops_total: Optional[float] = None) -> Roofline:
     """Build the three-term roofline from one compiled executable."""
-    ca = normalize_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     txt = compiled.as_text()

@@ -33,7 +33,7 @@ from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import apply_method, get_arch
 from repro.data import SyntheticLM, SyntheticLMConfig
 from repro.distributed.sharding import batch_specs, tree_param_specs
-from repro.launch.mesh import make_host_mesh, make_production_mesh, compat_set_mesh
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import AdamWConfig, linear_warmup_linear_decay
 from repro.train.step import TrainTask, init_train_state, make_train_step
 
@@ -73,7 +73,8 @@ def main() -> None:
     cfg = spec.smoke() if args.smoke else spec.full()
     cfg = apply_method(cfg, args.method)
     cfg = dataclasses.replace(cfg, remat=not args.no_remat,
-                              max_seq_len=max(cfg.max_seq_len, args.seq_len))
+                              max_seq_len=max(cfg.max_seq_len, args.seq_len),
+                              train_seq_len=args.seq_len)
     loss_kind = "clm" if cfg.causal else "frames"
 
     task = TrainTask(
@@ -82,7 +83,7 @@ def main() -> None:
         schedule=linear_warmup_linear_decay(args.steps // 10, args.steps),
         microbatch=args.microbatch, grad_compress=args.grad_compress)
 
-    with compat_set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), task)
         state_specs = tree_param_specs(state, args.profile, mesh)
         state = jax.device_put(state, _ns(mesh, state_specs))

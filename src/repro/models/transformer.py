@@ -106,9 +106,13 @@ class ModelConfig:
     # paper knobs
     softmax_cfg: ClippedSoftmaxConfig = ClippedSoftmaxConfig()
     gate_cfg: GateConfig = GateConfig(kind="none")
+    # the length the model is trained at: T of the clipped softmax's
+    # gamma = -alpha / T on every path, training and serving alike
+    train_seq_len: Optional[int] = None
 
-    # paged-KV read path: "auto" (Pallas kernel on TPU, XLA gather
-    # elsewhere) | "kernel" | "gather" — see core.attention.paged_attention
+    # paged-KV read path: "auto" (Pallas kernel where it runs compiled, XLA
+    # gather elsewhere — kernels.ops.default_interpret) | "kernel" |
+    # "gather" — see core.attention.paged_attention
     paged_backend: str = "auto"
 
     # embedding / io
@@ -162,6 +166,7 @@ class ModelConfig:
             logit_softcap=self.attn_logit_softcap,
             softmax=self.softmax_cfg,
             chunk_size=self.attn_chunk_size,
+            seq_len=self.train_seq_len,
         )
 
 
@@ -317,7 +322,7 @@ def _attn_block_apply(
         act_tok = _token_mask(active, b, t)   # (B, T) or None
         ring_read = None
         if is_paged:
-            # Paged pool (num_blocks, block_size, Hkv, Dh): every write is
+            # Paged pool (num_blocks, Hkv, block_size, Dh): every write is
             # routed through block_table[row, pos // block_size] indirection.
             # Unallocated targets (table entry -1) and inactive tokens are
             # redirected out of bounds and dropped, the same masked-scatter
@@ -332,7 +337,7 @@ def _attn_block_apply(
             # (token value, logical position). Ring and recurrent caches
             # lack this replay property, so the scheduler refuses spec
             # there.
-            nb, bs = cache["k"].shape[0], cache["k"].shape[1]
+            nb, bs = cache["k"].shape[0], cache["k"].shape[2]
             table = cache["block_table"]                         # (B, W)
             tpos = jnp.broadcast_to(_positions(pos, t), (b, t))  # logical
             phys = jnp.take_along_axis(table, tpos // bs, axis=1,
@@ -340,6 +345,9 @@ def _attn_block_apply(
             if act_tok is not None:
                 phys = jnp.where(act_tok, phys, -1)
             phys = jnp.where(phys < 0, nb, phys)    # out of bounds -> dropped
+            slot = tpos % bs
+            # pool[phys, :, slot] is (B, T, Hkv, Dh): numpy's rule puts the
+            # broadcast index dims first when a slice separates them
             if "k_scale" in cache:
                 # int8 pool: quantization fused into the same masked scatter.
                 # Each token is quantized exactly ONCE from its fp value —
@@ -349,21 +357,21 @@ def _attn_block_apply(
                 # (see quant.kv_cache for why not a scalar per-block scale).
                 kq, ks = kv_quant(k)
                 vq, vs = kv_quant(v)
-                k_cache = cache["k"].at[phys, tpos % bs].set(kq, mode="drop")
-                v_cache = cache["v"].at[phys, tpos % bs].set(vq, mode="drop")
+                k_cache = cache["k"].at[phys, :, slot].set(kq, mode="drop")
+                v_cache = cache["v"].at[phys, :, slot].set(vq, mode="drop")
                 new_cache = {
                     "k": k_cache, "v": v_cache,
-                    "k_scale": cache["k_scale"].at[phys, tpos % bs].set(
+                    "k_scale": cache["k_scale"].at[phys, 0, slot].set(
                         ks, mode="drop"),
-                    "v_scale": cache["v_scale"].at[phys, tpos % bs].set(
+                    "v_scale": cache["v_scale"].at[phys, 0, slot].set(
                         vs, mode="drop"),
                     "block_table": table,
                 }
                 paged_scales = (new_cache["k_scale"], new_cache["v_scale"])
             else:
-                k_cache = cache["k"].at[phys, tpos % bs].set(
+                k_cache = cache["k"].at[phys, :, slot].set(
                     k.astype(cache["k"].dtype), mode="drop")
-                v_cache = cache["v"].at[phys, tpos % bs].set(
+                v_cache = cache["v"].at[phys, :, slot].set(
                     v.astype(cache["v"].dtype), mode="drop")
                 new_cache = {"k": k_cache, "v": v_cache, "block_table": table}
             paged_table = table
@@ -412,16 +420,6 @@ def _attn_block_apply(
                         jnp.concatenate(
                             [cache["v"], v.astype(cache["v"].dtype)], axis=1),
                     )
-                    # the concat KV axis (L + T) varies with chunk size, but
-                    # alpha-resolved clipping must be invariant to how the
-                    # prompt is chunked: pin gamma to the ring length — the
-                    # axis every other ring path (decode t==1, one-shot
-                    # scalar prefill) resolves it from
-                    if not acfg.softmax.is_vanilla:
-                        acfg = dataclasses.replace(
-                            acfg, softmax=ClippedSoftmaxConfig(
-                                gamma=acfg.softmax.resolve_gamma(cache_len),
-                                zeta=acfg.softmax.zeta))
                 explicit_mask = (kp >= 0) & (kp <= q_pos) & \
                     (kp > q_pos - cfg.window)
                 acfg = dataclasses.replace(acfg, causal=False, window=None)
@@ -639,10 +637,12 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
                      num_blocks: int, block_size: int = 16,
                      dtype=None, kv_int8: bool = False) -> Params:
     """Paged decode state (vLLM-style): each global-attention layer holds a
-    shared block pool ``k``/``v`` of shape (num_blocks, block_size, Hkv, Dh)
-    plus a per-row ``block_table`` (batch, ceil(max_len / block_size)) of
-    physical block ids (-1 = unallocated). Cache memory scales with *live
-    tokens* (num_blocks * block_size across the whole batch) instead of
+    shared head-major block pool ``k``/``v`` of shape
+    (num_blocks, Hkv, block_size, Dh) — the Pallas kernel reads one
+    (block_size, Dh) tile of it per grid step — plus a per-row
+    ``block_table`` (batch, ceil(max_len / block_size)) of physical block
+    ids (-1 = unallocated). Cache memory scales with *live tokens*
+    (num_blocks * block_size across the whole batch) instead of
     batch * max_len, and ``max_len`` becomes a per-row logical cap only.
 
     Block tables are owned by the scheduler (host side): allocation and
@@ -652,8 +652,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
     Ring (local_attn) and recurrent states keep their dense per-row layout;
     they are already O(window) / O(1) per row.
 
-    ``kv_int8=True`` stores the pools as int8 plus per-block scale vectors
-    ``k_scale``/``v_scale`` of shape (num_blocks, block_size) — one f32
+    ``kv_int8=True`` stores the pools as int8 plus per-block scale rows
+    ``k_scale``/``v_scale`` of shape (num_blocks, 1, block_size) — one f32
     scale per token slot, written by the same masked scatter that writes
     the pool (see quant.kv_cache). KV block memory drops ~3.5x for typical
     head shapes (``paged_kv_block_bytes``), so an equal-byte pool holds
@@ -665,24 +665,23 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
     if max_len % block_size:
         raise ValueError(
             f"max_len={max_len} must be a multiple of block_size="
-            f"{block_size}: the virtual KV length (table width * "
-            f"block_size) must equal the logical cap so paged and dense "
-            f"attention see the same KV axis length — softmax_cfg.alpha "
-            f"resolves gamma = -alpha/T from it, so a padded axis would "
-            f"silently change the clip threshold")
+            f"{block_size}: the block table covers exactly max_len "
+            f"tokens")
     n_entries = max_len // block_size
 
     def one(kind: str):
         if kind == "attn":
             pool_dtype = jnp.int8 if kv_int8 else dtype
             c = {
-                "k": jnp.zeros((num_blocks, block_size, hkv, dh), pool_dtype),
-                "v": jnp.zeros((num_blocks, block_size, hkv, dh), pool_dtype),
+                "k": jnp.zeros((num_blocks, hkv, block_size, dh), pool_dtype),
+                "v": jnp.zeros((num_blocks, hkv, block_size, dh), pool_dtype),
                 "block_table": jnp.full((batch, n_entries), -1, jnp.int32),
             }
             if kv_int8:
-                c["k_scale"] = jnp.zeros((num_blocks, block_size), jnp.float32)
-                c["v_scale"] = jnp.zeros((num_blocks, block_size), jnp.float32)
+                c["k_scale"] = jnp.zeros((num_blocks, 1, block_size),
+                                         jnp.float32)
+                c["v_scale"] = jnp.zeros((num_blocks, 1, block_size),
+                                         jnp.float32)
             return c
         return _cache_entry(cfg, kind, batch, max_len, dtype)
 

@@ -62,8 +62,7 @@ def _linear_int8_apply(p: Params, x: Array, ctx: QuantContext,
     lead = x.shape[:-1]
     y = int8_matmul(
         x.reshape(-1, x.shape[-1]), p["w_q8"], p["w_scale"],
-        x_scale=s_x, x_zero=z_x,
-        interpret=jax.default_backend() != "tpu")
+        x_scale=s_x, x_zero=z_x)
     y = y.reshape(*lead, p["w_q8"].shape[-1])
     if "b" in p:
         y = y + p["b"].astype(y.dtype)

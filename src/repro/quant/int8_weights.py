@@ -12,7 +12,7 @@ the paper's methods removed the activation outliers.
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +85,8 @@ def attach_int8_weights(params: Any, skip: Tuple[str, ...] = (r".*lm_head.*",)
 
 
 def linear_int8(cache: Dict[str, Tuple[Array, Array]], path: str,
-                x: Array, bias: Array = None, interpret: bool = True) -> Array:
+                x: Array, bias: Array = None,
+                interpret: Optional[bool] = None) -> Array:
     """Run one cached linear through the integer kernel."""
     wq, s = cache[path]
     lead = x.shape[:-1]

@@ -1,9 +1,9 @@
 """Int8 paged-KV quantization: per-block scale vectors for the block pools.
 
 The paged serving cache (``models.transformer.init_paged_cache``) stores K/V
-in global block pools ``(num_blocks, block_size, Hkv, Dh)``. With
+in global block pools ``(num_blocks, Hkv, block_size, Dh)``. With
 ``kv_int8=True`` the pools hold int8 and each pool block carries a *scale
-vector* ``(num_blocks, block_size)`` — one f32 scale per token slot of the
+row* ``(num_blocks, 1, block_size)`` — one f32 scale per token slot of the
 block, symmetric int8 over that token's (Hkv, Dh) values:
 
     scale[nb, s] = max|kv[nb, s]| / 127        q = round(kv / scale)

@@ -223,27 +223,40 @@ def step_rows_full(params, cfg: ModelConfig, cache, tokens: Array,
     return logits, aux["cache"]
 
 
-def make_mixed_step(cfg: ModelConfig, gen: GenerateConfig,
-                    ctx: QuantContext = NO_QUANT):
-    """Build the jitted fused engine tick ``ContinuousBatcher`` runs every
-    step: one ``step_rows`` forward advancing every runnable row at its own
-    position — decode rows by 1 token, prefill rows by a chunk; padding
-    tokens' writes are dropped inside model_apply (masked per-token
-    scatter) — followed by position-keyed sampling. ``live_width``
-    (static) bounds the paged attention read to the allocated block-table
-    prefix and ``live_widths`` masks each row's read at its own block
-    count; ``keys`` are per-request PRNG keys — the sampled token at
-    position p is ``fold_in(key, p)``, so recompute-resume (and swap
-    resume) replay identical samples. ``ctx`` carries calibrated int8
-    ranges as jit closure constants (the W8A8 tick)."""
+def mixed_tick(cfg: ModelConfig, gen: GenerateConfig,
+               ctx: QuantContext = NO_QUANT):
+    """The fused engine tick as a plain (unjitted) function: one
+    ``step_rows`` forward advancing every runnable row at its own position
+    — decode rows by 1 token, prefill rows by a chunk; padding tokens'
+    writes are dropped inside model_apply (masked per-token scatter) —
+    followed by position-keyed sampling. Returns ``(next tokens, cache,
+    last-position logits)``; ``make_mixed_step`` jits it without the
+    logits. ``live_width`` (static) bounds the paged attention read to the
+    allocated block-table prefix and ``live_widths`` masks each row's read
+    at its own block count; ``keys`` are per-request PRNG keys — the
+    sampled token at position p is ``fold_in(key, p)``, so recompute-resume
+    (and swap resume) replay identical samples. ``ctx`` carries calibrated
+    int8 ranges as jit closure constants (the W8A8 tick)."""
 
-    def _mixed_step(params, cache, tokens, pos, counts, keys,
-                    live_width, live_widths):
+    def tick(params, cache, tokens, pos, counts, keys, live_width,
+             live_widths):
         last, new_cache = step_rows(
             params, cfg, cache, tokens, pos, counts,
             paged_live_width=live_width, paged_live_widths=live_widths,
             ctx=ctx)
-        nxt = sample_rows(last, gen, keys, pos + counts)
+        return sample_rows(last, gen, keys, pos + counts), new_cache, last
+
+    return tick
+
+
+def make_mixed_step(cfg: ModelConfig, gen: GenerateConfig,
+                    ctx: QuantContext = NO_QUANT):
+    """Build the jitted fused engine tick ``ContinuousBatcher`` runs every
+    step (``mixed_tick``), returning ``(next tokens, cache)``."""
+    tick = mixed_tick(cfg, gen, ctx)
+
+    def _mixed_step(*args):
+        nxt, new_cache, _ = tick(*args)
         return nxt, new_cache
 
     return jax.jit(_mixed_step, static_argnums=(6,))

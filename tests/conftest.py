@@ -2,94 +2,12 @@
 # multi-device sharding tests spawn subprocesses with their own XLA_FLAGS
 # (see test_sharding_dryrun.py).
 #
-# When `hypothesis` is not installed, a minimal stand-in is registered in
-# sys.modules BEFORE test modules import it, so the property tests degrade
-# to fixed-seed sampled cases (deterministic, capped example counts)
-# instead of failing collection. Only the strategy surface this suite uses
-# is implemented: given / settings / st.{integers,floats,booleans,
-# sampled_from}.
-import sys
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import functools
-    import inspect
-    import types
-    import zlib
-
-    import numpy as _np
-
-    _MAX_EXAMPLES_CAP = 8   # keep the degraded mode fast; hypothesis proper
-    #                         runs the full max_examples when installed
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def _integers(min_value=0, max_value=1 << 16):
-        return _Strategy(
-            lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-    def _floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(
-            lambda rng: float(rng.uniform(min_value, max_value)))
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.integers(0, 2)))
-
-    def _sampled_from(elements):
-        elems = list(elements)
-        return _Strategy(lambda rng: elems[int(rng.integers(0, len(elems)))])
-
-    def _settings(max_examples=10, deadline=None, **_kw):
-        def deco(fn):
-            fn._mini_hyp_max_examples = max_examples
-            return fn
-        return deco
-
-    def _given(**strategies):
-        def deco(fn):
-            n = min(getattr(fn, "_mini_hyp_max_examples", 10),
-                    _MAX_EXAMPLES_CAP)
-            seed = zlib.crc32(fn.__qualname__.encode())
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                rng = _np.random.default_rng(seed)
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                    fn(*args, **dict(kwargs, **drawn))
-
-            # hide the drawn parameters from pytest's fixture resolution
-            sig = inspect.signature(fn)
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items()
-                if name not in strategies])
-            del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.__doc__ = "Fixed-seed fallback shim (hypothesis not installed)."
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
-# --------------------------------------------------------------------------
 # jit compile-count guard (repro.analysis.compile_guard). Registering the
 # module as a plugin runs its pytest_configure (marker registration + jit
 # tracking install) before test modules import repro.*, so every wrapper
 # the suite creates is counted. The autouse fixture enforces
 # @pytest.mark.compile_budget(n) budgets.
-import pytest  # noqa: E402
+import pytest
 
 pytest.register_assert_rewrite("repro.analysis.compile_guard")
 pytest_plugins = ("repro.analysis.compile_guard",)

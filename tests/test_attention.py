@@ -34,7 +34,7 @@ SOFTMAXES = [
 def test_dense_vs_chunked(sm, window):
     q, k, v = _qkv()
     cfg = AttentionConfig(n_heads=8, n_kv_heads=4, d_head=16, causal=True,
-                          window=window, softmax=sm, chunk_size=32)
+                          window=window, softmax=sm, chunk_size=32, seq_len=96)
     np.testing.assert_allclose(
         dense_attention(q, k, v, cfg), chunked_attention(q, k, v, cfg),
         atol=3e-5)

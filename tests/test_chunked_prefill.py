@@ -26,8 +26,8 @@ KEY = jax.random.PRNGKey(0)
 def _tiny(**kw):
     base = dict(name="tiny", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
                 d_ff=64, vocab_size=64, pos="rope", max_seq_len=1024,
-                scan_layers=False, remat=False, mlp_kind="swiglu",
-                norm="rmsnorm")
+                train_seq_len=32, scan_layers=False, remat=False,
+                mlp_kind="swiglu", norm="rmsnorm")
     base.update(kw)
     return ModelConfig(**base)
 
@@ -137,11 +137,12 @@ class TestChunkedVsOneShot:
         (separate KV entries), so multi-token writes cannot evict history
         earlier queries of the same chunk still need — and the nonzero
         summands keep their logical order, so equality stays bitwise.
-        alpha-resolved gamma must pin to the RING length, not the
-        chunk-size-dependent concat axis (L + T), or clipping thresholds
-        drift with the chunking. init_std=0.5 keeps attention probs spread
-        enough that clipping genuinely engages (at tiny init every prob
-        clips to zero and the gamma assertions would be vacuous)."""
+        alpha-resolved gamma comes from the config's training length, not
+        the chunk-size-dependent concat axis (L + T), so clipping
+        thresholds cannot drift with the chunking. init_std=0.5 keeps
+        attention probs spread enough that clipping genuinely engages (at
+        tiny init every prob clips to zero and the gamma assertions would
+        be vacuous)."""
         cfg = apply_method(
             _tiny(pattern=("attn", "local_attn"), window=8, max_seq_len=64,
                   init_std=0.5),

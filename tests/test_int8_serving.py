@@ -164,9 +164,14 @@ class TestInt8KVPool:
                        or p.endswith("'v_scale']")]
         assert scale_paths, "no int8 attn pools in the cache"
         tight_checked = 0
+        # token-major views of the head-major pools: (NB, BS, Hkv, Dh)
+        # codes and (NB, BS) scales
+        def slots(pool):
+            return pool.transpose(0, 2, 1, 3)
+
         for sp in scale_paths:
             pool_p = sp.replace("_scale", "")
-            q, s = i8[pool_p], i8[sp]
+            q, s = slots(i8[pool_p]), i8[sp][:, 0]
             assert q.dtype == np.int8
             # tail slots of the written block + both unwritten blocks
             assert not q[2, 5:].any() and not s[2, 5:].any(), sp
@@ -175,7 +180,7 @@ class TestInt8KVPool:
                 # deeper layers see inputs already perturbed by layer 0's
                 # KV dequant, so the fp pool is no longer a tight oracle
                 continue
-            ref = fp[pool_p]
+            ref = slots(fp[pool_p])
             # tokens 0..4 land in physical block 2 (table[0] == 2)
             got = q[2, :5].astype(np.float32) * s[2, :5, None, None]
             err = np.abs(got - ref[2, :5])

@@ -82,6 +82,23 @@ class TestInt8Matmul:
         r = int8_matmul_ref(x, wq, ws)
         np.testing.assert_allclose(o, r, atol=1e-3, rtol=1e-4)
 
+    @pytest.mark.parametrize("static", [False, True])
+    def test_one_sided_range_keeps_all_codes(self, static):
+        """A ReLU output has zero-point 0 and codes up to 255: the kernel
+        must fold the zero-point out exactly, not saturate x_q - z to int8
+        (which would clip the upper half of the range)."""
+        x = jax.nn.relu(jax.random.normal(KEY, (64, 256)))
+        w = jax.random.normal(jax.random.PRNGKey(1), (256, 128)) * 0.05
+        wq, ws = quantize_weights_int8(w)
+        s = float(jnp.max(x)) / 255.0
+        kw = dict(x_scale=s, x_zero=0.0) if static else {}
+        o = int8_matmul(x, wq, ws, block_m=64, block_n=64, block_k=64, **kw)
+        np.testing.assert_allclose(o, int8_matmul_ref(x, wq, ws),
+                                   atol=1e-3, rtol=1e-4)
+        f = x @ w
+        rel = float(jnp.mean(jnp.abs(o - f)) / jnp.mean(jnp.abs(f)))
+        assert rel < 0.03, rel
+
     def test_quality_vs_float(self):
         """W8A8 of outlier-free activations is within ~2%% of fp matmul —
         the regime the paper's method creates."""

@@ -156,9 +156,8 @@ class TestPagedVsDenseBatcher:
 
     @pytest.mark.slow
     def test_clipped_softmax_paged_matches_dense(self):
-        """gamma = -alpha/T resolves from the KV axis length, so paged and
-        dense batchers must present identical KV lengths (init_paged_cache
-        enforces block_size | max_len) — outputs stay exactly equal under
+        """gamma = -alpha/T resolves from the config's training length on
+        both backends, so paged and dense batchers stay exactly equal under
         the paper's clipped softmax, not just vanilla."""
         from repro.configs import apply_method
         cfg, _ = _setup()
@@ -202,7 +201,7 @@ class TestPagedVsDenseBatcher:
 
     @pytest.mark.slow
     def test_scanned_layers_paged(self):
-        """Scanned caches stack the pools (G, num_blocks, bs, H, D) and the
+        """Scanned caches stack the pools (G, num_blocks, H, bs, D) and the
         tables (G, B, W); the batcher must thread both through lax.scan."""
         cfg = _tiny(scan_layers=True, max_seq_len=64)
         params = model_init(KEY, cfg)

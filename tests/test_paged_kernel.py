@@ -6,7 +6,9 @@ ratios, causal + local-window masks over logical positions from ragged
 per-row ``q_offset`` vectors, unallocated (-1) table entries, partially
 filled tail blocks, logit soft-capping, vanilla vs clipped softmax
 (gamma/zeta, including alpha-resolved gamma) vs gated attention, dtypes,
-and the static ``live_width`` prefix slicing the scheduler uses.
+and the static ``live_width`` prefix slicing the scheduler uses. Pools are
+head-major (num_blocks, Hkv, block_size, Dh); an alpha-resolved gamma uses
+the case's logical length W*block_size as the model's fixed length.
 
 Accumulation order differs (blockwise streaming vs materialized einsum),
 so agreement is to f32 round-off (atol 2e-5; bf16 2e-2), not bitwise —
@@ -36,8 +38,8 @@ def _case(b=3, w=4, bs=8, hq=4, hkv=2, dh=16, tq=1, dtype=jnp.float32,
     nb = b * w + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (b, tq, hq, dh), dtype)
-    k_pool = jax.random.normal(ks[1], (nb, bs, hkv, dh), dtype)
-    v_pool = jax.random.normal(ks[2], (nb, bs, hkv, dh), dtype)
+    k_pool = jax.random.normal(ks[1], (nb, hkv, bs, dh), dtype)
+    v_pool = jax.random.normal(ks[2], (nb, hkv, bs, dh), dtype)
     rng = np.random.default_rng(seed)
     max_pos = w * bs - tq
     pos = rng.integers(0, max_pos + 1, size=b) if ragged \
@@ -81,7 +83,8 @@ class TestPagedKernelFast:
     @pytest.mark.parametrize("sm", SOFTMAXES)
     def test_softmax_variants_ragged_positions(self, sm):
         q, kp, vp, tbl, pos, _ = _case()
-        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16, softmax=sm)
+        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16, softmax=sm,
+                              seq_len=32)
         _check(q, kp, vp, tbl, pos, cfg)
 
     def test_gated_clipped(self):
@@ -99,17 +102,17 @@ class TestPagedKernelFast:
     def test_softcap(self):
         q, kp, vp, tbl, pos, _ = _case()
         cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16,
-                              logit_softcap=30.0,
+                              logit_softcap=30.0, seq_len=32,
                               softmax=ClippedSoftmaxConfig(alpha=4.0))
         _check(q, kp, vp, tbl, pos, cfg)
 
     def test_live_width_slicing_exact(self):
         """Slicing the read to the allocated prefix must not change the
-        result — including the alpha-resolved clip threshold, which is
-        pinned to the LOGICAL length before slicing."""
+        result — including the alpha-resolved clip threshold, which comes
+        from the config's fixed length, not the visited KV axis."""
         q, kp, vp, tbl, pos, _ = _case(w=8, seed=3)
         held = int(np.max(np.sum(np.asarray(tbl) >= 0, axis=1)))
-        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16,
+        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16, seq_len=64,
                               softmax=ClippedSoftmaxConfig(alpha=4.0))
         full = paged_attention(q, kp, vp, tbl, cfg, q_offset=pos,
                                backend="gather")
@@ -128,7 +131,7 @@ class TestPagedKernelFast:
         the per-row mask where the bare -1 test would read them."""
         q, kp, vp, tbl, pos, _ = _case(w=8, seed=3)
         counts = np.sum(np.asarray(tbl) >= 0, axis=1)
-        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16,
+        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16, seq_len=64,
                               softmax=ClippedSoftmaxConfig(alpha=4.0))
         lws = jnp.asarray(counts, jnp.int32)
         # at a FIXED table width the per-row mask is bitwise-neutral (the
@@ -194,7 +197,7 @@ class TestPagedKernelSweep:
         q, kp, vp, tbl, pos, gate = _case(hq=group * hkv, hkv=hkv, w=5,
                                           seed=group)
         cfg = AttentionConfig(n_heads=group * hkv, n_kv_heads=hkv, d_head=16,
-                              window=window, softmax=sm)
+                              window=window, softmax=sm, seq_len=40)
         _check(q, kp, vp, tbl, pos, cfg, gate=gate)
 
     @pytest.mark.parametrize("tq", [2, 5])
@@ -202,7 +205,7 @@ class TestPagedKernelSweep:
         """Tq > 1 (speculative / chunked-prefill shapes): causal masking
         inside the query block over logical positions."""
         q, kp, vp, tbl, pos, gate = _case(tq=tq, w=5, seed=tq)
-        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16,
+        cfg = AttentionConfig(n_heads=4, n_kv_heads=2, d_head=16, seq_len=40,
                               softmax=ClippedSoftmaxConfig(alpha=4.0))
         _check(q, kp, vp, tbl, pos, cfg, gate=gate)
 
@@ -231,7 +234,8 @@ class TestKernelEndToEnd:
 
         base = ModelConfig(name="tiny", n_layers=2, d_model=32, n_heads=4,
                            n_kv_heads=2, d_ff=64, vocab_size=64, pos="rope",
-                           max_seq_len=1024, scan_layers=False, remat=False,
+                           max_seq_len=1024, train_seq_len=32,
+                           scan_layers=False, remat=False,
                            mlp_kind="swiglu", norm="rmsnorm",
                            softmax_cfg=ClippedSoftmaxConfig(alpha=4.0))
         params = model_init(KEY, base)

@@ -80,8 +80,9 @@ MINI_DRYRUN = textwrap.dedent("""
     from repro.configs import SHAPES, get_arch, apply_method
     from repro.launch.dryrun import build_lowered
     from repro.launch.roofline import analyze
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     spec = get_arch("{arch}")
     # reduced-width full-family config so the 16-dev compile is fast
     cfg = apply_method(spec.smoke(), "clipped_softmax")

@@ -49,6 +49,68 @@ class TestClippedSoftmax:
         assert cfg.resolve_gamma(128) == pytest.approx(-4.0 / 128)
         assert not cfg.is_vanilla
 
+    @pytest.mark.parametrize("backend", ["gather", "kernel"])
+    def test_gamma_fixed_by_model_length(self, monkeypatch, backend):
+        """gamma = -alpha / train_seq_len on every attention path, whatever
+        KV axis it reads: opt_tiny(seq_len=64) (max_seq_len 256) clips at
+        -4/64 in a training forward at T=64, a cache-free forward of 8
+        tokens, and dense and paged decode against 128-token caches; an
+        alpha config with no training length is refused."""
+        import dataclasses
+        import importlib
+        from repro.configs import apply_method
+        from repro.configs.paper_models import opt_tiny
+        from repro.kernels import paged_attention
+        from repro.models.transformer import (init_cache, init_paged_cache,
+                                              model_apply, model_init)
+        # the module, not the `attention` function repro.core exports
+        attention = importlib.import_module("repro.core.attention")
+        cfg = apply_method(opt_tiny(vocab=64, seq_len=64), "clipped_softmax",
+                           alpha=4.0)
+        cfg = dataclasses.replace(cfg, n_layers=1, paged_backend=backend)
+        assert cfg.max_seq_len == 256
+        seen = []
+
+        def clip_spy(p, gamma, zeta):
+            seen.append(gamma)
+            return stretch_and_clip(p, gamma, zeta)
+
+        def mha_spy(*a, gamma, **kw):
+            seen.append(gamma)
+            return real_mha(*a, gamma=gamma, interpret=True,
+                            **{k: v for k, v in kw.items() if k != "interpret"})
+
+        real_mha = paged_attention.paged_mha
+        monkeypatch.setattr(attention, "stretch_and_clip", clip_spy)
+        monkeypatch.setattr(paged_attention, "paged_mha", mha_spy)
+        params = model_init(KEY, cfg)
+        one = {"tokens": jnp.ones((1, 1), jnp.int32)}
+        paged = init_paged_cache(cfg, 1, 128, num_blocks=8)
+        for layer in paged["layers"]:
+            layer["b0"]["block_table"] = jnp.arange(8, dtype=jnp.int32)[None]
+        runs = {
+            "train": lambda: model_apply(
+                params, cfg, {"tokens": jnp.ones((2, 64), jnp.int32)}),
+            "no cache": lambda: model_apply(
+                params, cfg, {"tokens": jnp.ones((1, 8), jnp.int32)}),
+            "dense decode": lambda: model_apply(
+                params, cfg, one, cache=init_cache(cfg, 1, 128), pos=5),
+            "paged decode": lambda: model_apply(params, cfg, one,
+                                                cache=paged, pos=5),
+        }
+        for name, run in runs.items():
+            seen.clear()
+            jax.eval_shape(run)
+            assert seen and all(g == pytest.approx(-4.0 / 64) for g in seen), \
+                (name, seen)
+        with pytest.raises(ValueError, match="trained at"):
+            apply_method(dataclasses.replace(opt_tiny(), train_seq_len=None),
+                         "clipped_softmax", alpha=4.0)
+        with pytest.raises(ValueError, match="trained at"):
+            attention.AttentionConfig(
+                n_heads=4, n_kv_heads=4, d_head=32,
+                softmax=ClippedSoftmaxConfig(alpha=4.0)).gamma
+
     def test_masked_positions_stay_zero(self):
         x = jax.random.normal(KEY, (2, 8))
         where = jnp.arange(8) < 5
