@@ -337,43 +337,44 @@ def _attn_block_apply(
             # (token value, logical position). Ring and recurrent caches
             # lack this replay property, so the scheduler refuses spec
             # there.
-            nb, bs = cache["k"].shape[0], cache["k"].shape[2]
-            table = cache["block_table"]                         # (B, W)
-            tpos = jnp.broadcast_to(_positions(pos, t), (b, t))  # logical
-            phys = jnp.take_along_axis(table, tpos // bs, axis=1,
-                                       mode="fill", fill_value=-1)
-            if act_tok is not None:
-                phys = jnp.where(act_tok, phys, -1)
-            phys = jnp.where(phys < 0, nb, phys)    # out of bounds -> dropped
-            slot = tpos % bs
-            # pool[phys, :, slot] is (B, T, Hkv, Dh): numpy's rule puts the
-            # broadcast index dims first when a slice separates them
-            if "k_scale" in cache:
-                # int8 pool: quantization fused into the same masked scatter.
-                # Each token is quantized exactly ONCE from its fp value —
-                # its int8 code + per-slot scale land together, so stored
-                # bits are a pure function of (value, logical position) and
-                # serving stays bitwise invariant to chunking/slots/resume
-                # (see quant.kv_cache for why not a scalar per-block scale).
-                kq, ks = kv_quant(k)
-                vq, vs = kv_quant(v)
-                k_cache = cache["k"].at[phys, :, slot].set(kq, mode="drop")
-                v_cache = cache["v"].at[phys, :, slot].set(vq, mode="drop")
-                new_cache = {
-                    "k": k_cache, "v": v_cache,
-                    "k_scale": cache["k_scale"].at[phys, 0, slot].set(
-                        ks, mode="drop"),
-                    "v_scale": cache["v_scale"].at[phys, 0, slot].set(
-                        vs, mode="drop"),
-                    "block_table": table,
-                }
-                paged_scales = (new_cache["k_scale"], new_cache["v_scale"])
-            else:
-                k_cache = cache["k"].at[phys, :, slot].set(
-                    k.astype(cache["k"].dtype), mode="drop")
-                v_cache = cache["v"].at[phys, :, slot].set(
-                    v.astype(cache["v"].dtype), mode="drop")
-                new_cache = {"k": k_cache, "v": v_cache, "block_table": table}
+            with jax.named_scope("attn/kv_write"):
+                nb, bs = cache["k"].shape[0], cache["k"].shape[2]
+                table = cache["block_table"]                         # (B, W)
+                tpos = jnp.broadcast_to(_positions(pos, t), (b, t))  # logical
+                phys = jnp.take_along_axis(table, tpos // bs, axis=1,
+                                           mode="fill", fill_value=-1)
+                if act_tok is not None:
+                    phys = jnp.where(act_tok, phys, -1)
+                phys = jnp.where(phys < 0, nb, phys)    # out of bounds -> dropped
+                slot = tpos % bs
+                # pool[phys, :, slot] is (B, T, Hkv, Dh): numpy's rule puts the
+                # broadcast index dims first when a slice separates them
+                if "k_scale" in cache:
+                    # int8 pool: quantization fused into the same masked scatter.
+                    # Each token is quantized exactly ONCE from its fp value —
+                    # its int8 code + per-slot scale land together, so stored
+                    # bits are a pure function of (value, logical position) and
+                    # serving stays bitwise invariant to chunking/slots/resume
+                    # (see quant.kv_cache for why not a scalar per-block scale).
+                    kq, ks = kv_quant(k)
+                    vq, vs = kv_quant(v)
+                    k_cache = cache["k"].at[phys, :, slot].set(kq, mode="drop")
+                    v_cache = cache["v"].at[phys, :, slot].set(vq, mode="drop")
+                    new_cache = {
+                        "k": k_cache, "v": v_cache,
+                        "k_scale": cache["k_scale"].at[phys, 0, slot].set(
+                            ks, mode="drop"),
+                        "v_scale": cache["v_scale"].at[phys, 0, slot].set(
+                            vs, mode="drop"),
+                        "block_table": table,
+                    }
+                    paged_scales = (new_cache["k_scale"], new_cache["v_scale"])
+                else:
+                    k_cache = cache["k"].at[phys, :, slot].set(
+                        k.astype(cache["k"].dtype), mode="drop")
+                    v_cache = cache["v"].at[phys, :, slot].set(
+                        v.astype(cache["v"].dtype), mode="drop")
+                    new_cache = {"k": k_cache, "v": v_cache, "block_table": table}
             paged_table = table
         elif per_row:
             # Masked per-token scatter: row b writes token j of its block at
@@ -464,13 +465,14 @@ def _attn_block_apply(
         gate_pi = gate_probs(p["gate"], cfg.gate_cfg, x_heads, h)
 
     if paged_table is not None:
-        k_scale, v_scale = paged_scales if paged_scales is not None else (None, None)
-        attn_out = paged_attention(q, k_all, v_all, paged_table, acfg,
-                                   q_offset=q_offset, gate_pi=gate_pi,
-                                   live_width=paged_live_width,
-                                   live_widths=paged_live_widths,
-                                   k_scale=k_scale, v_scale=v_scale,
-                                   backend=cfg.paged_backend)
+        with jax.named_scope("attn/paged"):
+            k_scale, v_scale = paged_scales if paged_scales is not None else (None, None)
+            attn_out = paged_attention(q, k_all, v_all, paged_table, acfg,
+                                       q_offset=q_offset, gate_pi=gate_pi,
+                                       live_width=paged_live_width,
+                                       live_widths=paged_live_widths,
+                                       k_scale=k_scale, v_scale=v_scale,
+                                       backend=cfg.paged_backend)
     elif explicit_mask is not None:
         attn_out = dense_attention(q, k_all, v_all, acfg, mask=explicit_mask,
                                    q_offset=q_offset, gate_pi=gate_pi)
@@ -487,22 +489,23 @@ def _attn_block_apply(
 
     moe_aux = {"load_balance": jnp.zeros((), jnp.float32),
                "router_z": jnp.zeros((), jnp.float32)}
-    if cfg.mlp_kind != "none":
-        h2 = norm_apply(cfg.norm, p["ln2"], x, ctx, name + "/ln2") \
-            if cfg.norm_position == "pre" else x
-        if cfg.moe is not None:
-            # inactive decode rows must not claim expert capacity: their
-            # tokens would displace live rows' tokens in the dropping
-            # dispatch (slot-major priority), silently changing live outputs
-            y2, moe_aux = moe_apply(p["moe"], h2, cfg.moe, ctx, name + "/moe",
-                                    active=active)
-        else:
-            y2 = mlp_apply(p["mlp"], h2, cfg.mlp_kind, ctx, name + "/mlp")
-        if cfg.post_block_norm:
-            y2 = norm_apply(cfg.norm, p["post_ln2"], y2, ctx, name + "/post_ln2")
-        x = x + y2
-        if cfg.norm_position == "post":
-            x = norm_apply(cfg.norm, p["ln2"], x, ctx, name + "/ln2")
+    with jax.named_scope("mlp"):
+        if cfg.mlp_kind != "none":
+            h2 = norm_apply(cfg.norm, p["ln2"], x, ctx, name + "/ln2") \
+                if cfg.norm_position == "pre" else x
+            if cfg.moe is not None:
+                # inactive decode rows must not claim expert capacity: their
+                # tokens would displace live rows' tokens in the dropping
+                # dispatch (slot-major priority), silently changing live outputs
+                y2, moe_aux = moe_apply(p["moe"], h2, cfg.moe, ctx, name + "/moe",
+                                        active=active)
+            else:
+                y2 = mlp_apply(p["mlp"], h2, cfg.mlp_kind, ctx, name + "/mlp")
+            if cfg.post_block_norm:
+                y2 = norm_apply(cfg.norm, p["post_ln2"], y2, ctx, name + "/post_ln2")
+            x = x + y2
+            if cfg.norm_position == "post":
+                x = norm_apply(cfg.norm, p["ln2"], x, ctx, name + "/ln2")
     return x, new_cache, attn_layer_out, moe_aux
 
 
@@ -805,7 +808,8 @@ def model_apply(
     Returns (logits (B,T,vocab) f32, aux) where aux may contain
     "attn_outputs" (stacked per-layer residual values) and "cache".
     """
-    x = _embed_inputs(params, cfg, batch, pos, ctx)
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, cfg, batch, pos, ctx)
     b, t, _ = x.shape
 
     rope = None
@@ -892,13 +896,14 @@ def model_apply(
     if cache is not None:
         aux["cache"] = new_cache
 
-    x = norm_apply(cfg.norm, params["final_norm"], x, ctx, "final_norm")
-    if "lm_head" in params:
-        logits = linear_apply(params["lm_head"], x, ctx, "lm_head").astype(jnp.float32)
-    else:
-        logits = embedding_attend(params["embed"], x, ctx, "lm_head")
-    logits = softcap(logits, cfg.final_logit_softcap)
-    if cfg.padded_vocab != cfg.vocab_size:
-        pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
-        logits = jnp.where(pad_mask, -1e30, logits)
+    with jax.named_scope("lm_head"):
+        x = norm_apply(cfg.norm, params["final_norm"], x, ctx, "final_norm")
+        if "lm_head" in params:
+            logits = linear_apply(params["lm_head"], x, ctx, "lm_head").astype(jnp.float32)
+        else:
+            logits = embedding_attend(params["embed"], x, ctx, "lm_head")
+        logits = softcap(logits, cfg.final_logit_softcap)
+        if cfg.padded_vocab != cfg.vocab_size:
+            pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
+            logits = jnp.where(pad_mask, -1e30, logits)
     return logits, aux

@@ -244,7 +244,9 @@ def mixed_tick(cfg: ModelConfig, gen: GenerateConfig,
             params, cfg, cache, tokens, pos, counts,
             paged_live_width=live_width, paged_live_widths=live_widths,
             ctx=ctx)
-        return sample_rows(last, gen, keys, pos + counts), new_cache, last
+        with jax.named_scope("sample"):
+            nxt = sample_rows(last, gen, keys, pos + counts)
+        return nxt, new_cache, last
 
     return tick
 

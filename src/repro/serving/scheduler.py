@@ -193,6 +193,7 @@ from repro.models.transformer import (
 from repro.quant.int8_weights import attach_int8_weights
 from repro.quant.ptq import calibrate
 from repro.quant.qconfig import NO_QUANT, QConfig
+from repro.serving import telemetry
 from repro.serving.decode import (
     GenerateConfig,
     make_mixed_step,
@@ -607,6 +608,12 @@ class ContinuousBatcher:
         # counts vector of the most recent sub-step (observability + tests:
         # a mixed tick shows >= 2 entries > 1 next to entries == 1)
         self.last_counts: Optional[np.ndarray] = None
+        # host spans of every tick (serving/telemetry.py): ``serve.step``
+        # carries this engine's id and the tick index, its children the
+        # tick's phases
+        self.telemetry = telemetry.RECORDER
+        self.engine_id = next(telemetry.ENGINE_IDS)
+        self.ticks = 0
         if paged:
             self.block_size = block_size
             n_entries = -(-max_len // block_size)
@@ -1438,10 +1445,30 @@ class ContinuousBatcher:
                  allow_preempt: bool = True) -> int:
         """Plan, assemble and run ONE fused forward; apply its results to
         the slots. Returns the number of rows that advanced."""
-        counts = self._plan(want_decode, want_prefill, allow_preempt)
+        rec = self.telemetry
+        with rec.span("serve.plan"):
+            counts = self._plan(want_decode, want_prefill, allow_preempt)
         run = np.flatnonzero(counts)
         if run.size == 0:
             return 0
+        with rec.span("serve.assemble"):
+            args, final = self._assemble(run, counts)
+        tokens, width = args[0], args[4]
+        with rec.span("serve.dispatch", T=tokens.shape[1], width=width,
+                      B=self.B,
+                      blocks=sum(len(self.slots[i].blocks) for i in run)):
+            nxt, self.cache = self._step_fn(self.params, self.cache, *args)
+        with rec.span("serve.wait"):
+            nt = np.asarray(nxt)
+        with rec.span("serve.apply"):
+            self._apply(run, counts, nt, final)
+        return int(run.size)
+
+    def _assemble(self, run: np.ndarray, counts: np.ndarray):
+        """The tick's arguments after ``params`` and ``cache`` for the rows
+        ``run`` — ``(tokens (B, T), pos, counts, keys, live_width,
+        live_widths)`` — and, per prefill row, whether its chunk ends its
+        feed. Uploads the block tables when they changed."""
         self.last_counts = counts.copy()
         # recurrent rows would feed any padding tail into their recurrence
         # (no per-token write index to mask), so uniform mode uses the
@@ -1476,11 +1503,13 @@ class ContinuousBatcher:
         # the step returns its block tables unchanged, so in steady state
         # (no admissions/retirements) the paged tick is as cheap as the
         # dense one: no table upload, no tree surgery
-        nxt, self.cache = self._step_fn(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos),
-            jnp.asarray(counts), jnp.asarray(keys),
-            self._live_width(), live_widths)
-        nt = np.asarray(nxt)
+        return (jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(counts),
+                jnp.asarray(keys), self._live_width(), live_widths), final
+
+    def _apply(self, run: np.ndarray, counts: np.ndarray, nt: np.ndarray,
+               final: Dict[int, bool]) -> None:
+        """Bank one forward's sampled tokens ``nt`` into the rows ``run``
+        fed; ``final`` marks the prefill rows whose chunk ends their feed."""
         spec_on = self.spec is not None
         self.last_tick_tokens += int(counts.sum())
         for i in run:
@@ -1513,7 +1542,6 @@ class ContinuousBatcher:
                     self._on_prefill_done(i)
             if s.generated and s.req.first_token_time is None:
                 s.req.first_token_time = self.now
-        return int(run.size)
 
     def _apply_spec_decode(self, i: int, tgt: np.ndarray, c: int) -> None:
         """Verify slot ``i``'s drafts against the (T,) target row of the
@@ -1719,6 +1747,13 @@ class ContinuousBatcher:
         it); omitted, it advances an internal tick counter by 1. Returns
         the number of rows advanced (0 = stalled or idle, never an
         exception under transient faults)."""
+        self.ticks += 1
+        with self.telemetry.span("serve.step", tick=self.ticks,
+                                 engine=self.engine_id):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> int:
+        rec = self.telemetry
         now = self.now + 1.0 if now is None else float(now)
         dt = now - self.now
         if dt > 0 and self._prev_advanced:
@@ -1730,9 +1765,12 @@ class ContinuousBatcher:
         self._alloc_fault = False
         self.last_tick_tokens = 0
         self.last_tick_new_tokens = 0
-        self._retire()
-        self._enforce_slos()
-        self._admit()
+        with rec.span("serve.retire"):
+            self._retire()
+        with rec.span("serve.slo"):
+            self._enforce_slos()
+        with rec.span("serve.admit"):
+            self._admit()
         if self._uniform:
             has_pre = any(s.req is not None and s.prefill is not None
                           for s in self.slots)
@@ -1743,7 +1781,8 @@ class ContinuousBatcher:
                                    allow_preempt=(n == 0))
         else:
             n = self._substep()
-        self._retire()
+        with rec.span("serve.retire"):
+            self._retire()
         self._prev_advanced = n > 0
         if self._alloc_fault and n == 0:
             self._fault_streak += 1
