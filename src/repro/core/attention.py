@@ -18,7 +18,9 @@ Three execution paths:
     and each batch row owns a *block table* of physical block ids. A
     dispatcher over two backends: the fused Pallas TPU kernel
     (``repro.kernels.paged_attention``) that reads pool blocks in place
-    through a scalar-prefetched block table (default on TPU), and
+    through a scalar-prefetched block table, several blocks of every KV
+    head per grid step and no step past a row's live blocks (default on
+    TPU), and
     ``paged_attention_gather`` — the XLA oracle that gathers the row's
     virtual KV sequence block-by-block, then masks per block: unallocated
     table entries (id < 0) contribute nothing, and the usual causal/window
@@ -400,7 +402,10 @@ def paged_attention(
       * ``"kernel"`` — the fused Pallas TPU kernel
         (``repro.kernels.paged_attention``): pool blocks are read in place
         through a scalar-prefetched block table; no gather, no materialized
-        virtual sequence.
+        virtual sequence. Its grid is ``(B, head_groups, ceil(W / P))``:
+        one step reads P consecutive table entries (P derived from the
+        block size: enough keys for 128 lanes, 8 at block 16) of every KV
+        head unless VMEM forces head groups.
       * ``"gather"`` — ``paged_attention_gather``, the XLA path that
         materializes each row's virtual KV sequence. Bitwise-equal to dense
         attention; the oracle the kernel is swept against.
@@ -419,12 +424,14 @@ def paged_attention(
     slicing is exact, not an approximation.
 
     ``live_widths``: optional (B,) int32 vector of each row's OWN live
-    entry count, masking the gather path's per-row read at the row rather
-    than the tick max (see ``paged_attention_gather``; the shapes stay
-    static — ``live_width`` bounds them, ``live_widths`` confines the valid
-    work inside them). The kernel backend ignores it: its per-block masks
-    already skip unallocated entries, and a per-row ``pl.when`` early exit
-    is on-TPU tuning work (ROADMAP).
+    entry count, confining each row's read to its own prefix rather than
+    the tick max (the shapes stay static — ``live_width`` bounds them,
+    ``live_widths`` confines the valid work inside them). The gather path
+    masks the entries past it (see ``paged_attention_gather``). The kernel
+    bounds each row at min(``live_widths[b]``, the causal bound
+    ceil((q_offset[b] + Tq) / block_size)) entries: its grid steps past
+    that bound fetch nothing and compute nothing
+    (``kernels.paged_attention.live_bound``).
 
     ``k_scale``/``v_scale``: per-slot scale rows (num_blocks, 1,
     block_size) of an int8 pool (``init_paged_cache(kv_int8=True)``).
@@ -448,7 +455,7 @@ def paged_attention(
                          causal=cfg.causal, window=cfg.window,
                          softcap=cfg.logit_softcap, gamma=gamma, zeta=zeta,
                          k_scale=k_scale, v_scale=v_scale,
-                         interpret=interpret)
+                         live_widths=live_widths, interpret=interpret)
     if backend != "gather":
         raise ValueError(f"unknown paged-attention backend {backend!r}")
     return paged_attention_gather(q, k_pool, v_pool, block_table, cfg,

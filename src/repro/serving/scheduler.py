@@ -183,6 +183,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import blocks_per_step, live_steps
 from repro.models.transformer import (
     ModelConfig,
     copy_pool_blocks,
@@ -1452,11 +1453,12 @@ class ContinuousBatcher:
         if run.size == 0:
             return 0
         with rec.span("serve.assemble"):
-            args, final = self._assemble(run, counts)
+            args, final, (steps, grid) = self._assemble(run, counts)
         tokens, width = args[0], args[4]
         with rec.span("serve.dispatch", T=tokens.shape[1], width=width,
                       B=self.B,
-                      blocks=sum(len(self.slots[i].blocks) for i in run)):
+                      blocks=sum(len(self.slots[i].blocks) for i in run),
+                      steps=steps, grid=grid):
             nxt, self.cache = self._step_fn(self.params, self.cache, *args)
         with rec.span("serve.wait"):
             nt = np.asarray(nxt)
@@ -1467,8 +1469,9 @@ class ContinuousBatcher:
     def _assemble(self, run: np.ndarray, counts: np.ndarray):
         """The tick's arguments after ``params`` and ``cache`` for the rows
         ``run`` — ``(tokens (B, T), pos, counts, keys, live_width,
-        live_widths)`` — and, per prefill row, whether its chunk ends its
-        feed. Uploads the block tables when they changed."""
+        live_widths)`` —, per prefill row whether its chunk ends its feed,
+        and the paged-attention grid steps that fetch and in all
+        (``_attn_grid``). Uploads the block tables when they changed."""
         self.last_counts = counts.copy()
         # recurrent rows would feed any padding tail into their recurrence
         # (no per-token write index to mask), so uniform mode uses the
@@ -1498,13 +1501,30 @@ class ContinuousBatcher:
         if self.paged and self._tables_dirty:
             self.cache = _with_tables(self.cache, jnp.asarray(self.tables))
             self._tables_dirty = False
-        live_widths = jnp.asarray([len(s.blocks) for s in self.slots],
-                                  jnp.int32) if self.paged else None
+        held = np.asarray([len(s.blocks) for s in self.slots], np.int32) \
+            if self.paged else None
+        width = self._live_width()
         # the step returns its block tables unchanged, so in steady state
         # (no admissions/retirements) the paged tick is as cheap as the
         # dense one: no table upload, no tree surgery
         return (jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(counts),
-                jnp.asarray(keys), self._live_width(), live_widths), final
+                jnp.asarray(keys), width,
+                None if held is None else jnp.asarray(held)), final, \
+            self._attn_grid(pos, t_step, held, width)
+
+    def _attn_grid(self, pos: np.ndarray, t: int,
+                   held: Optional[np.ndarray],
+                   width: Optional[int]) -> Tuple[int, int]:
+        """The paged-attention kernel's grid along rows and the table, for
+        the tick's own ``pos``, T and per-row held blocks: the steps that
+        fetch (each row's ``live_steps``, summed) and all of them
+        (B x ceil(width / P)). (0, 0) in dense mode."""
+        if not self.paged:
+            return 0, 0
+        steps = live_steps(pos, t, held, block_size=self.block_size,
+                           width=width, causal=self.cfg.causal)
+        p = blocks_per_step(self.block_size, width)
+        return int(steps.sum()), self.B * -(-width // p)
 
     def _apply(self, run: np.ndarray, counts: np.ndarray, nt: np.ndarray,
                final: Dict[int, bool]) -> None:

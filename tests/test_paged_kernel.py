@@ -23,7 +23,8 @@ import pytest
 
 from repro.core.attention import AttentionConfig, paged_attention
 from repro.core.softmax import ClippedSoftmaxConfig
-from repro.kernels.paged_attention import paged_mha
+from repro.kernels.paged_attention import (blocks_per_step, live_bound,
+                                           live_steps, paged_mha)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -67,6 +68,42 @@ def _check(q, k_pool, v_pool, table, pos, cfg, gate=None, live_width=None,
     assert out.dtype == ref.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol)
+
+
+def _live_case(w, bs, hq, hkv, tq, int8=False, seed=0, b=4, dh=16):
+    """Rows at ragged positions that own MORE blocks than their causal
+    bound needs (up to two spare entries, as a speculative or
+    just-grown row does) — so their ``live_widths`` lie past the causal
+    bound — plus one row that holds nothing (live width 0)."""
+    nb = b * w + 2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    rng = np.random.default_rng(seed)
+    q = jax.random.normal(ks[0], (b, tq, hq, dh), jnp.float32)
+    if int8:
+        k_pool = jax.random.randint(ks[1], (nb, hkv, bs, dh), -127, 128,
+                                    jnp.int8)
+        v_pool = jax.random.randint(ks[2], (nb, hkv, bs, dh), -127, 128,
+                                    jnp.int8)
+        scales = tuple(jax.random.uniform(k, (nb, 1, bs), jnp.float32,
+                                          0.005, 0.05) for k in ks[3:5])
+    else:
+        k_pool = jax.random.normal(ks[1], (nb, hkv, bs, dh), jnp.float32)
+        v_pool = jax.random.normal(ks[2], (nb, hkv, bs, dh), jnp.float32)
+        scales = (None, None)
+    pos = rng.integers(0, w * bs - tq + 1, size=b)
+    pos[0] = 0
+    table = np.full((b, w), -1, np.int32)
+    live = np.zeros(b, np.int32)
+    perm = rng.permutation(nb)
+    nxt = 0
+    for i in range(1, b):
+        need = -(-(int(pos[i]) + tq) // bs)
+        live[i] = min(w, need + int(rng.integers(0, 3)))
+        table[i, :live[i]] = perm[nxt:nxt + live[i]]
+        nxt += live[i]
+    gate = jax.nn.sigmoid(jax.random.normal(ks[5], (b, tq, hq)))
+    return (q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(pos, jnp.int32),
+            gate, live, scales)
 
 
 SOFTMAXES = [
@@ -182,6 +219,66 @@ class TestPagedKernelFast:
             out = paged_attention(q, kp, vp, tbl, cfg, q_offset=pos,
                                   backend=backend, interpret=True)
             assert not np.asarray(out[1]).any(), backend
+
+
+# (table width W, block size, Hq, Hkv, Tq, int8 pools, softmax): P is the
+# fewest entries filling 128 lanes, capped by W — 4 at block 32, 2 at 64 —
+# so each row spans several grid steps, and W is no multiple of P
+LIVE_CASES = {
+    "ragged-G2": (10, 32, 4, 2, 1, False, ClippedSoftmaxConfig()),
+    "ragged-G1-int8": (10, 32, 2, 2, 1, True, ClippedSoftmaxConfig()),
+    "spec-k3-clipped": (9, 32, 4, 2, 4, False,
+                        ClippedSoftmaxConfig(gamma=-0.03)),
+    "spec-k3-clipped-int8": (9, 32, 2, 2, 4, True,
+                             ClippedSoftmaxConfig(alpha=4.0)),
+    "block64-G2-window": (7, 64, 4, 2, 2, False,
+                          ClippedSoftmaxConfig(gamma=-0.02, zeta=1.03)),
+}
+
+
+class TestPagedKernelLiveBound:
+    """The kernel's grid of P entries per step and its per-row live bound
+    against the gather oracle given the same ``live_widths`` — fast tier."""
+
+    @pytest.mark.parametrize("name", sorted(LIVE_CASES))
+    def test_ragged_live_widths(self, name):
+        w, bs, hq, hkv, tq, int8, sm = LIVE_CASES[name]
+        q, kp, vp, tbl, pos, gate, live, (ks, vs) = _live_case(
+            w, bs, hq, hkv, tq, int8=int8, seed=len(name))
+        p = blocks_per_step(bs, w)
+        bound = live_bound(np.asarray(pos), tq, live, block_size=bs, width=w)
+        assert w % p and 1 < p < w
+        assert live[0] == 0 and (bound % p).any()     # empty row; mid-step end
+        assert (bound < live).any()                   # causal bound binds
+        cfg = AttentionConfig(n_heads=hq, n_kv_heads=hkv, d_head=16,
+                              softmax=sm, seq_len=w * bs,
+                              window=20 if "window" in name else None)
+        kw = dict(q_offset=pos, gate_pi=gate, k_scale=ks, v_scale=vs,
+                  live_widths=jnp.asarray(live))
+        ref = paged_attention(q, kp, vp, tbl, cfg, backend="gather", **kw)
+        out = paged_attention(q, kp, vp, tbl, cfg, backend="kernel",
+                              interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5 if not int8 else 2e-4)
+        assert not np.asarray(out[0]).any()           # nothing live: zeros
+
+    def test_step_counts(self):
+        """``live_steps``: min(live width, causal bound, W) entries, P per
+        step; numpy in, numpy out."""
+        pos = np.array([0, 0, 37, 100, 5])
+        live = np.array([0, 3, 9, 10, 10])
+        assert blocks_per_step(32, 10) == 4 and blocks_per_step(8, 4) == 4
+        bound = live_bound(pos, 4, live, block_size=32, width=10)
+        np.testing.assert_array_equal(bound, [0, 1, 2, 4, 1])
+        steps = live_steps(pos, 4, live, block_size=32, width=10)
+        assert isinstance(steps, np.ndarray)
+        np.testing.assert_array_equal(steps, [0, 1, 1, 1, 1])
+        np.testing.assert_array_equal(
+            live_steps(pos, 4, None, block_size=32, width=10, causal=False),
+            [3] * 5)
+        np.testing.assert_array_equal(
+            live_steps(pos + 300, 4, live, block_size=32, width=10),
+            [0, 1, 3, 3, 3])
 
 
 class TestPagedKernelSweep:

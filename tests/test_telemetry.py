@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs.paper_models import opt_tiny
+from repro.kernels.paged_attention import blocks_per_step, live_steps
 from repro.models import model_init
 from repro.serving import ContinuousBatcher, Request, telemetry
 
@@ -36,7 +37,8 @@ def _engine():
 
 def _tap(eng):
     """Wrap the engine's tick and live width to record, per call, what the
-    tick was given: counts, T, the width and the blocks of the fed rows."""
+    tick was given: counts, T, the width, the blocks of the fed rows, the
+    rows' positions and their held blocks."""
     calls, widths = [], []
     step_fn, live_width = eng._step_fn, eng._live_width
 
@@ -48,7 +50,8 @@ def _tap(eng):
         fed = np.flatnonzero(np.asarray(counts))
         calls.append(dict(counts=np.asarray(counts), T=tokens.shape[1],
                           width=w, last_width=widths[-1],
-                          blocks=sum(len(eng.slots[i].blocks) for i in fed)))
+                          blocks=sum(len(eng.slots[i].blocks) for i in fed),
+                          pos=np.asarray(pos), held=np.asarray(ws)))
         return step_fn(params, cache, tokens, pos, counts, keys, w, ws)
 
     eng._live_width, eng._step_fn = width, tick
@@ -112,7 +115,28 @@ def test_dispatch_counters_match_what_the_tick_was_given(ticks):
         assert d.attrs["width"] == call["width"] == call["last_width"]
         assert d.attrs["blocks"] == call["blocks"] > 0
         assert d.attrs["T"] == call["T"] and d.attrs["B"] == 4
-        assert set(d.attrs) == {"T", "width", "B", "blocks"}
+        assert set(d.attrs) == {"T", "width", "B", "blocks", "steps", "grid"}
+
+
+def test_dispatch_attention_grid_matches_the_kernels_step_function(ticks):
+    """``steps`` and ``grid`` on ``serve.dispatch`` are what the paged
+    kernel's own step function gives for the tick's inputs: the steps that
+    fetch, summed over rows, and B x ceil(width / P)."""
+    seen = []
+    for tk in ticks:
+        if not tk["call"]:
+            continue
+        _, kids = _tree(tk["spans"])
+        (d,) = [k for k in kids if k.name == "serve.dispatch"]
+        call = tk["call"]
+        w = call["width"]
+        want = live_steps(call["pos"], call["T"], call["held"], block_size=8,
+                          width=w)
+        assert d.attrs["steps"] == int(want.sum()) > 0
+        assert d.attrs["grid"] == 4 * -(-w // blocks_per_step(8, w))
+        seen.append((d.attrs["steps"], d.attrs["grid"]))
+    # slots that hold nothing fetch nothing: some tick uses part of its grid
+    assert all(s <= g for s, g in seen) and any(s < g for s, g in seen)
 
 
 def test_engines_of_one_process_are_told_apart():

@@ -13,6 +13,7 @@ only one process may hold the TPU library, and under pytest-xdist every
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,33 @@ def test_paged_attention_compiles(one_chip, variant, kv_int8, tq):
                          k_scale=ks, v_scale=vs, interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+def test_paged_attention_compiles_at_the_cells_prefill_tick(one_chip):
+    """The benchmark cell's largest paged-attention call: its T=256 tick
+    bucket over 64 rows at table width 128, gated, int8 pools of 8,192
+    blocks, with per-row live widths. Every Pallas call of the module is
+    named ``paged_attention*``, the prefix the benchmark's kernel time and
+    roofline read."""
+    b, t, w, nb = 64, 256, 128, 8192
+    args = [_sds((b, t, HQ, DH), jnp.bfloat16, one_chip),
+            _sds((nb, HKV, BS, DH), jnp.int8, one_chip),
+            _sds((nb, HKV, BS, DH), jnp.int8, one_chip),
+            _sds((b, w), jnp.int32, one_chip),
+            _sds((b,), jnp.int32, one_chip),
+            _sds((b, t, HQ), jnp.bfloat16, one_chip),
+            _sds((nb, 1, BS), jnp.float32, one_chip),
+            _sds((nb, 1, BS), jnp.float32, one_chip),
+            _sds((b,), jnp.int32, one_chip)]
+
+    def fn(q, k_pool, v_pool, table, off, gate, ks, vs, held):
+        return paged_mha(q, k_pool, v_pool, table, off, gate, k_scale=ks,
+                         v_scale=vs, live_widths=held, interpret=False)
+
+    names = re.findall(
+        r"%([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        _compiled_text(fn, *args))
+    assert names and all(n.startswith("paged_attention") for n in names)
 
 
 # the q/k/v/o projections (D x D), a fused q/k/v projection (D x 3D), the
