@@ -46,12 +46,14 @@ def verdict(gaps: np.ndarray, limit: float) -> Dict:
 
 
 def _rows(req, max_len: int):
-    """The padded sequence, the positions that produced the served tokens
-    (padded to a power of two by repeating the last, so the reference
-    compiles a few shapes, not one per output length) and the tokens."""
+    """The sequence fed, padded to the smallest power of two that holds it
+    (at most ``max_len``), the positions that produced the served tokens
+    (padded to a power of two by repeating the last) and the tokens. Powers
+    of two let the reference compile a few shapes, not one per length; the
+    causal reference reads no padding at the positions compared."""
     p, o = np.asarray(req.prompt, np.int32), np.asarray(req.output, np.int32)
-    seq = np.zeros(max_len, np.int32)
     fed = np.concatenate([p, o[:-1]])
+    seq = np.zeros(min(max_len, 1 << (len(fed) - 1).bit_length()), np.int32)
     seq[:len(fed)] = fed
     n = 1 << (len(o) - 1).bit_length()
     at = np.full(n, len(p) - 2 + len(o), np.int32)

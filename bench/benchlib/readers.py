@@ -44,49 +44,53 @@ def rows(tick):
 def paged_attn_roofline(run) -> Optional[float]:
     """Roofline time of what each row's own context needs, over the device
     time of the ``paged_attention*`` kernels. One kernel call per layer and
-    tick covers every row."""
+    tick covers every row; the roofline is summed over the layers."""
     red = run.reduced
     if red is None:
         return None
     kernel = red.kernel_time("paged_attention")
     if kernel <= 0:
         return None
-    layers = counts.dims(run.model)["layers"]
+    groups = run.geometry.groups()
     peak_f, bw = run.peaks["bf16_flops_per_s"], run.peaks["hbm_bytes_per_s"]
     best, bound = 0.0, {}
     for tk in run.window.ticks:
-        fl = by = 0.0
-        for p, c in rows(tk):
-            f, b = counts.attn_row_cost(p, c, run.model, run.layout)
-            fl, by = fl + f, by + b
-        if fl or by:
-            t, which = counts.roofline_s(fl, by, peak_f, bw)
-            best += t * layers
-            bound[which] = bound.get(which, 0) + 1
+        tick_rows = rows(tk)
+        for layer, n in groups:
+            fl = by = 0.0
+            for p, c in tick_rows:
+                f, b = counts.attn_row_cost(p, c, layer, run.layout)
+                fl, by = fl + f, by + b
+            if fl or by:
+                t, which = counts.roofline_s(fl, by, peak_f, bw)
+                best += t * n
+                bound[which] = bound.get(which, 0) + n
     run.notes["paged_attn_bound"] = bound
     return best / kernel * 100.0
 
 
 def int8_matmul_roofline(run) -> Optional[float]:
-    """Roofline time of each linear layer at M = real tokens fed, over the
-    device time of the ``int8_matmul*`` kernels."""
+    """Roofline time of each dense linear (``per_token`` 1) at M = real
+    tokens fed, over the device time of the ``int8_matmul*`` kernels.
+    Routed experts are left to a metric that reads their routing."""
     red = run.reduced
     if red is None or run.precision != "w8a8":
         return None
     kernel = red.kernel_time("int8_matmul")
     if kernel <= 0:
         return None
-    layers = counts.dims(run.model)["layers"]
+    dense = [(lin, n) for layer, n in run.geometry.groups()
+             for lin in layer.linears if lin.per_token == 1]
     peak_i, bw = run.peaks["int8_ops_per_s"], run.peaks["hbm_bytes_per_s"]
     best, bound = 0.0, {}
     for tk in run.window.ticks:
         if not tk.fed:
             continue
-        for k, n in counts.linear_shapes(run.model):
-            ops, by = counts.int8_matmul_cost(tk.fed, k, n)
+        for lin, n in dense:
+            ops, by = counts.int8_matmul_cost(tk.fed, lin.k, lin.n)
             t, which = counts.roofline_s(ops, by, peak_i, bw)
-            best += t * layers
-            bound[which] = bound.get(which, 0) + layers
+            best += t * n
+            bound[which] = bound.get(which, 0) + n
     run.notes["int8_matmul_bound"] = bound
     return best / kernel * 100.0
 
@@ -97,7 +101,7 @@ def mfu(run) -> Optional[float]:
     red = run.reduced
     if red is None or not red.busy:
         return None
-    ops = sum(counts.tick_model_ops(rows(tk), tk.banked, run.model)
+    ops = sum(counts.tick_model_ops(rows(tk), tk.banked, run.geometry)
               for tk in run.window.ticks)
     if ops <= 0:
         return None

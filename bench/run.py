@@ -7,6 +7,25 @@ entry names, its traffic mix from ``bench/traffic/<traffic>.json`` and each
 metric's reader from ``bench/metrics/<metric>.py``, so a new cell, mix,
 configuration or metric is new files and entries, never an edit here.
 
+Beside the configuration file lie two modules it names. Its program module
+(``program.module``) has ``program_config(config)``, the program's model
+config checked against the file's published widths, and
+``geometry(config)``, the ``benchlib.counts.Geometry`` that the roofline and
+``mfu`` readers count with. Its reference (``reference``) has
+``make_params(model, init, seed, dtype)`` and ``logits_fn(model, bits=None)``
+and imports nothing of the program. A configuration of another model family
+joins by adding, with no file under ``bench/`` edited:
+
+- the configuration JSON in ``bench/configs/`` (``source``, ``model``,
+  ``reduced``, ``assumed``, ``departures``, ``program``, ``reference``,
+  ``init``, ``precision``, ``engine``, ``layout``, ``control``, ``limits``);
+- its program module and its reference, beside it;
+- its traffic JSON in ``bench/traffic/``, if the mix is new;
+- its ``configs`` and ``workloads`` entries in ``BENCHMARK.json``, and the
+  new cell's name in the ``workloads`` list of each end-to-end and
+  per-layer metric that applies to it;
+- a reader in ``bench/metrics/`` for each metric of what the family adds.
+
 Set-up (counted in ``setup_s`` from process start): weights drawn from the
 seed on the device, the engine built (W8A8 calibration included), every
 tick program the window can reach compiled or loaded from the persistent
@@ -41,7 +60,7 @@ from typing import Optional  # noqa: E402
 BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 
-from benchlib import check, driver, trace, traffic  # noqa: E402
+from benchlib import check, counts, driver, trace, traffic  # noqa: E402
 from benchlib.peaks import UnknownDevice, peaks_for  # noqa: E402
 
 TRACE_DIR = ".bench_trace"
@@ -104,7 +123,7 @@ class Spec:
 @dataclasses.dataclass
 class Run:
     """What the metric readers read."""
-    model: dict
+    geometry: counts.Geometry
     layout: dict
     precision: str
     peaks: dict
@@ -158,33 +177,6 @@ class CompileCounter:
         return out
 
 
-def program_config(config: dict):
-    """The program's ModelConfig for this configuration, checked against
-    the published widths the configuration file states."""
-    from repro.configs import apply_method, paper_models, to_bf16
-
-    prog = config["program"]
-    gate = prog.get("gate", {})
-    cfg = apply_method(getattr(paper_models, prog["constructor"])(),
-                       prog["method"], pi_init=gate.get("pi_init", 0.5),
-                       gate_kind=gate.get("kind", "linear"))
-    cfg = to_bf16(cfg)
-    m = config["model"]
-    want = {"n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_attention_heads"], "d_ff": m["ffn_dim"],
-            "vocab_size": m["vocab_size"], "padded_vocab": m["vocab_size"],
-            "max_seq_len": m["max_position_embeddings"],
-            "head_dim": m["hidden_size"] // m["num_attention_heads"],
-            "mlp_kind": m["activation_function"],
-            "norm_position": "pre" if m["do_layer_norm_before"] else "post",
-            "tie_embeddings": True, "pos": "learned", "causal": True}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want or m["word_embed_proj_dim"] != m["hidden_size"]:
-        raise ValueError(f"program config {got} is not the published {want}")
-    return cfg
-
-
 def check_layout(jax, params, cfg) -> None:
     """The benchmark's weights have exactly the program's layout."""
     from repro.models import model_init
@@ -210,8 +202,9 @@ def run_cell(spec: Spec, seed: int, seconds: float, traced: bool,
     counter = CompileCounter(jax)
     phases = {"imports": time.perf_counter() - T_START}
     config, mix = spec.config, spec.mix
-    ref_mod = _load_reference(spec)
-    cfg = program_config(config)
+    prog_mod = _load_beside(spec, config["program"]["module"])
+    ref_mod = _load_beside(spec, config["reference"])
+    cfg = prog_mod.program_config(config)
     params = ref_mod.make_params(config["model"], config["init"],
                                  config["init"]["seed"], jnp.bfloat16)
     check_layout(jax, params, cfg)
@@ -267,8 +260,9 @@ def run_cell(spec: Spec, seed: int, seconds: float, traced: bool,
         reduced = trace.reduce(tr, *lo_hi) if lo_hi else None
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    run = Run(config["model"], config["layout"], config["precision"], peaks,
-              marks["setup_s"], window, reduced, {})
+    run = Run(prog_mod.geometry(config), config["layout"],
+              config["precision"], peaks, marks["setup_s"], window, reduced,
+              {})
     metrics = {}
     for m in spec.metrics(traced):
         v = spec.reader(m["name"])(run)
@@ -326,11 +320,13 @@ def run_cell(spec: Spec, seed: int, seconds: float, traced: bool,
     return out
 
 
-def _load_reference(spec: Spec):
+def _load_beside(spec: Spec, name: str):
+    """The module ``<name>.py`` beside the cell's configuration file: its
+    program module or its reference."""
     entry = {c["name"]: c for c in spec.bench["configs"]}[spec.cell["config"]]
-    path = (spec.root / entry["file"]).parent / f"{spec.config['reference']}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_reference_" + spec.config["reference"], path)
+    path = (spec.root / entry["file"]).parent / f"{name}.py"
+    mod_spec = importlib.util.spec_from_file_location("bench_config_" + name,
+                                                      path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod

@@ -1,11 +1,14 @@
 """One cell end to end on the CPU at ``opt_tiny`` size, with the harness's
 look for a chip patched inside the test: the last line's keys and metric
-names, a mix added as files only, a token altered where it is produced
-(``correct`` must come out false), the control failing the limit that the
-program passes, and the unpatched command refusing to run off TPU."""
+names, a mix and a configuration of another family added as files only, a
+token altered where it is produced (``correct`` must come out false), the
+control failing the limit that the program passes, and the unpatched
+command refusing to run off TPU."""
+import hashlib
 import io
 import json
 import os
+import shutil
 import subprocess
 import sys
 from contextlib import redirect_stdout
@@ -79,6 +82,75 @@ def test_new_mix_is_files_and_an_entry(harness, tmp_path):
     assert set(res["metrics"]) == {"setup_s", "out_tok_s"}
     info = json.loads(lines[-2].removeprefix("info "))
     assert info["ticks"] > 0 and info["requests_finished"] > 0
+
+
+def _digests(base: Path) -> dict:
+    """SHA-256 of every file under ``base`` but tests and byte code."""
+    out = {}
+    for p in base.rglob("*"):
+        rel = p.relative_to(base)
+        if p.is_file() and not {"__pycache__", "tests"} & set(rel.parts):
+            out[rel] = hashlib.sha256(p.read_bytes()).hexdigest()
+    return out
+
+
+def test_new_configuration_is_files_and_an_entry(harness, tmp_path,
+                                                 monkeypatch):
+    """A configuration of another family (RMSNorm, RoPE, GQA 4:1 at a head
+    size other than d/h, SwiGLU, an untied head) joins from a temporary
+    root as its file, its program module, its reference and entries. Its
+    cell runs untraced and traced and is correct. The CPU has no device
+    plane, so the traced run is given one: a ``fusion`` and a
+    ``paged_attention`` op inside every ``bench.step`` span, for the
+    readers of device time to count the new geometry against. No file of
+    the repository's ``bench/`` changes."""
+    from benchlib import trace
+    root = tiny.make_root(tmp_path)
+    configs = root / "bench" / "configs"
+    for mod in ("rope_gqa", "rope_gqa_program"):
+        shutil.copy(BENCH / "tests" / f"{mod}.py", configs / f"{mod}.py")
+    (configs / "rope-gqa-tiny.json").write_text(json.dumps(tiny.ROPE_GQA))
+    cell = "rope-gqa-tiny.tiny-closed"
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "rope-gqa-tiny", "source": "test",
+                             "file": "bench/configs/rope-gqa-tiny.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": cell, "config": "rope-gqa-tiny",
+                               "traffic": "tiny-closed", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m and m["name"] != "int8_matmul_roofline":
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    real_load = trace.load
+
+    def load(path, *a, **k):
+        tr = real_load(path, *a, **k)
+        tr.device_ops["/device:TPU:0"] = [
+            (name, s + f0 * (e - s), s + f1 * (e - s))
+            for n, s, e in tr.spans if n == "bench.step"
+            for name, f0, f1 in (("fusion", 0.1, 0.3),
+                                 ("paged_attention", 0.3, 0.6))]
+        return tr
+    monkeypatch.setattr(trace, "load", load)
+
+    rc, lines = _main(harness, root, cell)
+    assert rc == 0
+    res = json.loads(lines[-1])
+    assert res["correct"] is True
+    assert set(res["metrics"]) == {"setup_s", "out_tok_s"}
+    assert res["metrics"]["out_tok_s"]["value"] > 0
+    rc, lines = _main(harness, root, cell, trace=1)
+    assert rc == 0
+    res = json.loads(lines[-1])
+    assert res["correct"] is True
+    got = {n: m["value"] for n, m in res["metrics"].items()}
+    assert {"mfu", "paged_attn_roofline"} <= set(got)
+    assert "int8_matmul_roofline" not in got
+    assert 0 < got["mfu"] <= 100 and 0 < got["paged_attn_roofline"] <= 100
+    repo, got = _digests(BENCH), _digests(root / "bench")
+    assert {p: got.get(p) for p in repo} == repo
 
 
 def test_altered_token_is_not_correct(harness, tmp_path, monkeypatch):

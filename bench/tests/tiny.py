@@ -78,3 +78,28 @@ def make_root(tmp: Path, precision: str = "bf16", limit: float = 0.05,
 # the W8A8 program read 0 to 0.011 and its int4 control 0.11 to 0.34; the
 # bf16 program read 0 (its int8 control 0 to 0.012, no separation here).
 LIMITS = {"bf16": 0.05, "w8a8": 0.05}
+
+
+# A decoder of another family than OPT that the program serves, at a tiny
+# size: pre-RMSNorm, RoPE, GQA 4:1 at head_dim 48 (not 128 / 8), SwiGLU and
+# an untied head. Its reference and program module are ``rope_gqa.py`` and
+# ``rope_gqa_program.py`` beside this file.
+ROPE_GQA_MODEL = {"hidden_size": 128, "num_attention_heads": 8,
+                  "num_key_value_heads": 2, "head_dim": 48,
+                  "intermediate_size": 256, "num_hidden_layers": 2,
+                  "vocab_size": 2048, "max_position_embeddings": 256,
+                  "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
+                  "hidden_act": "silu", "tie_word_embeddings": False}
+
+ROPE_GQA = {
+    "source": "test", "model": ROPE_GQA_MODEL, "reduced": [],
+    "program": {"module": "rope_gqa_program", "name": "rope-gqa-tiny"},
+    "reference": "rope_gqa",
+    "init": {"std": 0.02, "qk_std": 0.05, "norm_std": 0.02,
+             "seed": 20240101},
+    "departures": [], "assumed": {"weights": "random, from init.seed"},
+    "precision": "bf16",
+    "engine": {"batch_size": 4, "max_len": 256, "block_size": 16,
+               "token_budget": 32, "num_blocks": 64},
+    "layout": {"kv_bytes": 2, "kv_scale_bytes": 0, "attn_q_bytes": 2},
+    "limits": {"max_gap": LIMITS["bf16"]}}
